@@ -127,5 +127,8 @@ def main() -> list:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     for line in main():
         print(line)

@@ -101,6 +101,27 @@ A100_40G = HardwareSpec(
     name="a100-40g", flops=312e12, hbm_bw=1555e9, host_bw=32e9, ici_bw=300e9
 )
 
+# Peaks by the ``device_kind`` JAX reports (a v5e reports "TPU v5 lite";
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s).
+HARDWARE_BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E}
+
+
+def hardware_for(device) -> HardwareSpec:
+    """The analytical model's peaks for ``device``.  On a TPU they are keyed
+    by ``device_kind`` and an unknown kind is an error, never a default;
+    off the TPU (CPU tests) the v5e table stands in as the planning
+    target."""
+    if device.platform != "tpu":
+        return TPU_V5E
+    try:
+        return HARDWARE_BY_DEVICE_KIND[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak table for device_kind {device.device_kind!r} "
+            f"(known: {sorted(HARDWARE_BY_DEVICE_KIND)})"
+        ) from None
+
 
 class LatencyModel(Protocol):
     def iter_time(self, shape: BatchShape) -> float: ...

@@ -272,22 +272,21 @@ def cache_shardings(cache_spec: PyTree, mesh: Mesh) -> PyTree:
 
 
 def pool_pspec(shape: Sequence[int], mesh: Mesh) -> P:
-    """Paged-pool leaves are (num_periods, num_blocks, block_size, Hkv, D).
+    """Paged-pool leaves are (num_periods, num_blocks, Hkv, block_size, D).
 
     Tensor-parallel serving shards the KV-HEAD axis over ``model``: every
     chip owns Hkv/tp heads of *every* physical block, so the block table
     stays replicated and identical on all chips and block allocation /
-    preemption / checkpoint bookkeeping is mesh-oblivious.  Head counts
-    that don't divide the axis replicate the pool instead — never the
-    head_dim: D is the contraction dim of the attention dots, and a
-    sharded contraction turns into partial-sum all-reduces whose float
-    summation order breaks the bitwise token identity the differential
-    harness asserts (DESIGN.md §11).
+    preemption / checkpoint bookkeeping is mesh-oblivious.  The heads must
+    divide the axis (``RealEngine`` refuses a mesh whose axis does not) —
+    never a silent replication, and never a head_dim split: D is the
+    contraction dim of the attention dots, and a sharded contraction turns
+    into partial-sum all-reduces whose float summation order breaks the
+    bitwise token identity the differential harness asserts (DESIGN.md §11).
     """
     spec: list = [None] * len(shape)
-    msize = mesh_axis_size(mesh, "model")
-    if msize > 1 and len(shape) == 5 and shape[3] % msize == 0:
-        spec[3] = "model"
+    if mesh_axis_size(mesh, "model") > 1:
+        spec[2] = "model"
     return P(*spec)
 
 

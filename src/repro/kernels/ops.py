@@ -1,9 +1,10 @@
 """Public jit'd entry points for the kernel layer.
 
-Backend dispatch: on TPU the Pallas kernels run compiled; everywhere else
-(this CPU container) they run in ``interpret=True`` mode, or fall back to
-the jnp reference for speed (interpret mode executes the kernel body
-python-side per grid step — exact but slow for large grids).
+Backend dispatch: on a TPU the Pallas kernels always run compiled.  Off
+the TPU the jnp reference runs by default, or the Pallas kernels in
+``interpret=True`` mode when ``REPRO_KERNEL_BACKEND=interpret`` (interpret
+mode executes the kernel body python-side per grid step — exact but slow
+for large grids).
 """
 from __future__ import annotations
 
@@ -16,8 +17,6 @@ from . import ref
 from .flash_attention import flash_attention as _flash_pallas
 from .kv_checkpoint import checkpoint_gather as _ckpt_pallas
 from .kv_checkpoint import checkpoint_scatter
-from .paged_attention import paged_attention as _paged_pallas
-from .paged_attention import paged_attention_sharded as _paged_shmap
 from .paged_attention import ragged_paged_attention as _ragged_pallas
 from .paged_attention import (
     ragged_paged_attention_sharded as _ragged_shmap,
@@ -34,11 +33,23 @@ __all__ = [
 
 
 def kernel_backend() -> str:
-    """'pallas' on TPU, 'interpret' when forced, else 'ref' (CPU default)."""
-    forced = os.environ.get("REPRO_KERNEL_BACKEND")
-    if forced:
-        return forced
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+    """'pallas' on a TPU; off it 'ref' (default) or 'interpret', chosen by
+    ``REPRO_KERNEL_BACKEND``.  The override never applies on a TPU: asking
+    for anything but the compiled kernels there is an error."""
+    forced = os.environ.get("REPRO_KERNEL_BACKEND") or None
+    if jax.default_backend() == "tpu":
+        if forced not in (None, "pallas"):
+            raise RuntimeError(
+                f"REPRO_KERNEL_BACKEND={forced!r}: a TPU runs the compiled "
+                "Pallas kernels only"
+            )
+        return "pallas"
+    if forced not in (None, "ref", "interpret"):
+        raise ValueError(
+            f"REPRO_KERNEL_BACKEND={forced!r}: off a TPU it is 'ref' or "
+            "'interpret'"
+        )
+    return forced or "ref"
 
 
 def flash_attention(q, k, v, *, causal=True, sliding_window=0, q_offset=0,
@@ -68,15 +79,11 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, *,
             q, k_pool, v_pool, block_tables, seq_lens,
             logit_softcap=logit_softcap,
         )
-    if mesh is not None:
-        return _paged_shmap(
-            q, k_pool, v_pool, block_tables, seq_lens, mesh,
-            logit_softcap=logit_softcap, interpret=(be == "interpret"),
-        )
-    return _paged_pallas(
-        q, k_pool, v_pool, block_tables, seq_lens,
-        logit_softcap=logit_softcap, interpret=(be == "interpret"),
-    )
+    # the Qmax = 1 case of the ragged kernel, the query at seq_len - 1
+    return ragged_paged_attention(
+        q[:, None], k_pool, v_pool, block_tables, (seq_lens - 1)[:, None],
+        seq_lens, logit_softcap=logit_softcap, mesh=mesh,
+    )[:, 0]
 
 
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, q_positions,

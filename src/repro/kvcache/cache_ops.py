@@ -1,8 +1,10 @@
 """JAX ops over the *paged* physical KV layout.
 
-Physical pool per layer: ``k_pool, v_pool: (num_blocks, block_size, Hkv, D)``.
-Sequences address it through ``block_tables: (B, max_blocks_per_seq) int32``
-(-1 padded) + ``seq_lens: (B,)``.
+Physical pool per layer: ``k_pool, v_pool: (num_blocks, Hkv, block_size, D)``
+— KV heads outside the block, so one head's page is a ``(block_size, D)``
+tile the Pallas kernel can load as a block.  Sequences address it through
+``block_tables: (B, max_blocks_per_seq) int32`` (-1 padded) +
+``seq_lens: (B,)``.
 
 These ops are the pure-jnp oracle for the Pallas ``paged_attention`` kernel
 and the physical half of the block manager's accounting.
@@ -27,13 +29,13 @@ def append_paged(
 
     Negative (padding) table entries drop the write instead of aliasing a
     real block — padded batch rows are harmless by construction."""
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     block_idx = seq_lens // bs
     offset = seq_lens % bs
     rows = jnp.take_along_axis(block_tables, block_idx[:, None], axis=1)[:, 0]
     rows = jnp.where(rows >= 0, rows, k_pool.shape[0])  # pad -> OOB -> drop
-    k_pool = k_pool.at[rows, offset].set(k_new, mode="drop")
-    v_pool = v_pool.at[rows, offset].set(v_new, mode="drop")
+    k_pool = k_pool.at[rows, :, offset].set(k_new, mode="drop")
+    v_pool = v_pool.at[rows, :, offset].set(v_new, mode="drop")
     return k_pool, v_pool
 
 
@@ -52,15 +54,15 @@ def write_paged_chunk(
     landing on padding (negative table entries, or beyond the table width)
     drop the write rather than aliasing a real block.
     """
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     m = block_tables.shape[1]
     in_table = positions // bs < m  # (B, L)
     block_idx = jnp.clip(positions // bs, 0, m - 1)
     offsets = positions % bs
     rows = jnp.take_along_axis(block_tables, block_idx, axis=1)  # (B, L)
     rows = jnp.where((rows >= 0) & in_table, rows, k_pool.shape[0])  # drop
-    k_pool = k_pool.at[rows, offsets].set(k_new, mode="drop")
-    v_pool = v_pool.at[rows, offsets].set(v_new, mode="drop")
+    k_pool = k_pool.at[rows, :, offsets].set(k_new, mode="drop")
+    v_pool = v_pool.at[rows, :, offsets].set(v_new, mode="drop")
     return k_pool, v_pool
 
 
@@ -82,13 +84,13 @@ def write_ragged(
     for symmetry with ``write_paged_chunk``) drop the write.
     """
     rows = jnp.where(dst_rows >= 0, dst_rows, k_pool.shape[0])
-    k_pool = k_pool.at[rows, dst_offsets].set(k_new, mode="drop")
-    v_pool = v_pool.at[rows, dst_offsets].set(v_new, mode="drop")
+    k_pool = k_pool.at[rows, :, dst_offsets].set(k_new, mode="drop")
+    v_pool = v_pool.at[rows, :, dst_offsets].set(v_new, mode="drop")
     return k_pool, v_pool
 
 
 def extract_block(pool: jnp.ndarray, block_id) -> jnp.ndarray:
-    """O(block) copy out of the pool by *physical* id: (bs, Hkv, D).
+    """O(block) copy out of the pool by *physical* id: (Hkv, bs, D).
 
     This (with ``write_block``) is the incremental-checkpoint unit — a
     preempt/resume moves whole physical blocks, never per-request pytrees.
@@ -102,7 +104,7 @@ def write_block(pool: jnp.ndarray, block_id, data: jnp.ndarray) -> jnp.ndarray:
 
 
 def copy_blocks(
-    pool: jnp.ndarray,  # (num_blocks, bs, Hkv, D)
+    pool: jnp.ndarray,  # (num_blocks, Hkv, bs, D)
     src_ids: jnp.ndarray,  # (N,) physical source blocks
     dst_ids: jnp.ndarray,  # (N,) physical destination blocks
 ) -> jnp.ndarray:
@@ -120,26 +122,26 @@ def copy_blocks(
 
 
 def gather_paged(
-    pool: jnp.ndarray,  # (num_blocks, bs, Hkv, D)
+    pool: jnp.ndarray,  # (num_blocks, Hkv, bs, D)
     block_tables: jnp.ndarray,  # (B, M)
     max_ctx: int,
 ) -> jnp.ndarray:
     """Gather per-sequence contiguous KV (B, max_ctx, Hkv, D)."""
-    bs = pool.shape[1]
+    _, hkv, bs, d = pool.shape
     m = max_ctx // bs
     tables = block_tables[:, :m]  # (B, m)
     safe = jnp.maximum(tables, 0)
-    gathered = pool[safe]  # (B, m, bs, Hkv, D)
+    gathered = pool[safe]  # (B, m, Hkv, bs, D)
     gathered = jnp.where(
         (tables >= 0)[:, :, None, None, None], gathered, 0
     )
     b = tables.shape[0]
-    return gathered.reshape(b, m * bs, *pool.shape[2:])
+    return gathered.transpose(0, 1, 3, 2, 4).reshape(b, m * bs, hkv, d)
 
 
 def paged_attention_ref(
     q: jnp.ndarray,  # (B, H, D) — single decode token per sequence
-    k_pool: jnp.ndarray,  # (num_blocks, bs, Hkv, D)
+    k_pool: jnp.ndarray,  # (num_blocks, Hkv, bs, D)
     v_pool: jnp.ndarray,
     block_tables: jnp.ndarray,  # (B, M)
     seq_lens: jnp.ndarray,  # (B,) tokens valid in the cache (incl. current)
@@ -147,7 +149,7 @@ def paged_attention_ref(
 ) -> jnp.ndarray:
     """Oracle decode attention over the paged pool. Returns (B, H, D)."""
     b, h, d = q.shape
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     m = block_tables.shape[1]
     max_ctx = m * bs
     k = gather_paged(k_pool, block_tables, max_ctx)  # (B, T, Hkv, D)
@@ -167,7 +169,7 @@ def paged_attention_ref(
 
 def ragged_paged_attention_ref(
     q: jnp.ndarray,  # (S, Qmax, H, D) — per-sequence padded query tokens
-    k_pool: jnp.ndarray,  # (num_blocks, bs, Hkv, D)
+    k_pool: jnp.ndarray,  # (num_blocks, Hkv, bs, D)
     v_pool: jnp.ndarray,
     block_tables: jnp.ndarray,  # (S, M)
     q_positions: jnp.ndarray,  # (S, Qmax) absolute position of each query
@@ -188,7 +190,7 @@ def ragged_paged_attention_ref(
     ``q_len = 1``.  Returns (S, Qmax, H, D).
     """
     s, tq, h, d = q.shape
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     m = block_tables.shape[1]
     max_ctx = m * bs
     k = gather_paged(k_pool, block_tables, max_ctx)  # (S, T, Hkv, D)
@@ -212,10 +214,10 @@ def ragged_paged_attention_ref(
 
 
 def checkpoint_gather_ref(
-    pool: jnp.ndarray,  # (num_blocks, bs, Hkv, D)
+    pool: jnp.ndarray,  # (num_blocks, Hkv, bs, D)
     block_ids: jnp.ndarray,  # (N,) device blocks to checkpoint
 ) -> jnp.ndarray:
     """Oracle for the incremental-checkpoint delta gather: pack the selected
-    blocks into a dense staging buffer (N, bs, Hkv, D) for one contiguous
+    blocks into a dense staging buffer (N, Hkv, bs, D) for one contiguous
     device→host DMA."""
     return pool[block_ids]

@@ -3,26 +3,33 @@
 Three modes:
   sim       — run the full-scale config under the calibrated discrete-event
               cost model (policy evaluation; used by the benchmarks).
-  real      — run the real-execution engine on CPU with a REDUCED variant of
-              the chosen architecture (true JAX compute; single-threaded:
-              submissions happen up front, then the engine drains).
+  real      — calibrate, then run the real-execution engine (true JAX
+              compute; single-threaded: submissions happen up front, then
+              the engine drains).
   wallclock — full serving stack (DESIGN.md §10): calibrate the engine's
               measured latency profile, run the engine loop on a background
               thread via CoServingRuntime, and drive the streaming/batch
               Frontend from this (the API) thread against the wall clock,
               printing ServiceMetrics at the end.
 
+``real`` and ``wallclock`` serve the architecture at its published widths
+(on a TPU) or, with ``--reduced``, a CPU-sized variant of the same family;
+both build the engine through ``build_engine``, as ``chip_smoke.py`` does.
+They exit non-zero when the runtime ends FAILED or any request failed.
+
 Examples:
   PYTHONPATH=src python -m repro.launch.serve --arch llama-2-7b --mode sim \
       --duration 120 --rate 2 --offline 500
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --mode real \
-      --online 4 --offline 8 --max-new 16
+      --reduced --online 4 --offline 8 --max-new 16
   PYTHONPATH=src python -m repro.launch.serve --arch llama-2-7b \
-      --mode wallclock --duration 3 --rate 4 --offline 8
+      --mode wallclock --reduced --duration 3 --rate 4 --offline 8
+  python -m repro.launch.serve --arch qwen2-0.5b --mode wallclock  # on a TPU
 """
 from __future__ import annotations
 
 import argparse
+import sys
 
 import numpy as np
 
@@ -68,18 +75,69 @@ def _serving_mesh(tp: int):
     return make_serving_mesh(tp)
 
 
-def run_real(args) -> None:
+def build_engine(arch: str, *, reduced: bool, seed: int, eng_cfg,
+                 sched_cfg=None, reduce_kw=None, num_layers=None):
+    """The one construction path of every real-execution entry point
+    (``--mode real``/``wallclock`` and ``chip_smoke.py``): the named
+    architecture at its published widths — cut to its first
+    ``num_layers`` layers when given, widths untouched — or, the explicit
+    CPU choice, its ``ModelConfig.reduced(**reduce_kw)`` variant.  Weights
+    are drawn from ``seed`` by one jitted program, so every mesh size gets
+    the same bits; on a serving mesh they are drawn straight into their
+    replicated placement, so no chip ever holds a second copy.  Returns
+    the ``RealEngine``; its ``cfg`` and ``params`` are the model served."""
+    import dataclasses
+    import functools
+
     import jax
 
     from repro.configs import get_config
     from repro.models import transformer as tf
-    from repro.serving.api import Frontend
-    from repro.serving.real_engine import RealEngine, RealEngineConfig
+    from repro.serving.real_engine import RealEngine
 
-    cfg = get_config(args.arch).reduced()
-    params = tf.init_params(cfg, jax.random.PRNGKey(args.seed))
-    eng = RealEngine(
-        cfg, params,
+    cfg = get_config(arch)
+    if reduced:
+        cfg = cfg.reduced(**(reduce_kw or {}))
+    elif jax.default_backend() != "tpu":
+        raise SystemExit(
+            f"{arch} at published widths needs a TPU (JAX found "
+            f"{jax.default_backend()}); pass --reduced to run on CPU"
+        )
+    if num_layers is not None:
+        cfg = dataclasses.replace(cfg, num_layers=num_layers)
+    placement = None
+    if eng_cfg.mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        placement = NamedSharding(eng_cfg.mesh, PartitionSpec())
+    init = jax.jit(
+        functools.partial(tf.init_params, cfg), out_shardings=placement
+    )
+    params = init(jax.random.PRNGKey(seed))
+    return RealEngine(cfg, params, sched_cfg=sched_cfg, eng_cfg=eng_cfg)
+
+
+def _exit_on_failure(failed: int, health=None, fatal=None) -> None:
+    """Entry-point verdict: a FAILED runtime or any failed request exits
+    non-zero, whatever metrics were printed before."""
+    if fatal is not None and fatal.traceback_text:
+        print(fatal.traceback_text, file=sys.stderr)
+    dead = health is not None and health.name == "FAILED"
+    if failed or dead:
+        print(f"serving failed: runtime FAILED={dead} "
+              f"failed_requests={failed}", file=sys.stderr)
+        sys.exit(1)
+
+
+def run_real(args) -> None:
+    import jax
+
+    from repro.core.request import Phase
+    from repro.serving.api import Frontend
+    from repro.serving.real_engine import RealEngineConfig
+
+    eng = build_engine(
+        args.arch, reduced=args.reduced, seed=args.seed,
         eng_cfg=RealEngineConfig(
             # size the KV capacity to the requested lengths, or admission
             # control rejects the default workload (longest job below is
@@ -88,6 +146,9 @@ def run_real(args) -> None:
             mesh=_serving_mesh(args.tp),
         ),
     )
+    cfg = eng.cfg
+    print("calibrating (also warms every jit bucket serving will hit)...")
+    eng.calibrate()
     fe = Frontend(eng)
     rng = np.random.default_rng(args.seed)
 
@@ -104,13 +165,15 @@ def run_real(args) -> None:
         max_new_tokens=args.max_new,
     )
     eng.run()
-    print(f"arch={cfg.name} (reduced) — real execution on {jax.default_backend()}")
+    print(f"arch={cfg.name} — real execution on {jax.default_backend()}")
     for i, h in enumerate(streams):
         print(f"stream {i}: {h.poll()}")
     print(f"batch job done={job.done} progress={job.progress:.0%}")
     print(f"engine steps={eng.steps} preemptions="
           f"{sum(r.num_preemptions for r in eng.sched.all_requests())} "
           f"ckpt_blocks={eng.ckpt.stats.blocks_checkpointed}")
+    reqs = [h.request for h in streams] + job.requests
+    _exit_on_failure(sum(r.phase != Phase.FINISHED for r in reqs))
 
 
 def _metrics_server(registry, port: int, health_cb=None):
@@ -164,20 +227,17 @@ def run_wallclock(args) -> None:
 
     import jax
 
-    from repro.configs import get_config
     from repro.core.profiler import BatchShape
     from repro.core.scheduler import SchedulerConfig
     from repro.core.slo import SLO
-    from repro.models import transformer as tf
     from repro.serving import loadgen
     from repro.serving.api import Frontend, QueueFull, QueueTimeout
-    from repro.serving.real_engine import RealEngine, RealEngineConfig
+    from repro.serving.real_engine import RealEngineConfig
     from repro.serving.runtime import CoServingRuntime, ServingConfig
 
-    cfg = get_config(args.arch).reduced(num_layers=4, safepoint_interval=1)
-    params = tf.init_params(cfg, jax.random.PRNGKey(args.seed))
-    eng = RealEngine(
-        cfg, params,
+    eng = build_engine(
+        args.arch, reduced=args.reduced, seed=args.seed,
+        reduce_kw=dict(num_layers=4, safepoint_interval=1),
         sched_cfg=SchedulerConfig(
             chunk_size=32, slo_aware=True, avg_ctx_estimate=64,
             max_batch_seqs=8,
@@ -187,6 +247,7 @@ def run_wallclock(args) -> None:
             mesh=_serving_mesh(args.tp),
         ),
     )
+    cfg = eng.cfg
     print("calibrating (also warms every jit bucket serving will hit)...")
     prof = eng.calibrate()
     t_chunk = prof.iter_time(BatchShape(
@@ -254,7 +315,7 @@ def run_wallclock(args) -> None:
     for th in consumers:
         th.join(timeout=5.0)
     m = rt.metrics()
-    print(f"arch={cfg.name} (reduced) wall-clock on {jax.default_backend()}")
+    print(f"arch={cfg.name} wall-clock on {jax.default_backend()}")
     print(f"online streams={len(streams)} finished="
           f"{sum(1 for h in streams if h.finished)} shed={shed} "
           f"policy={args.backpressure}; batch done={job.done}")
@@ -272,6 +333,12 @@ def run_wallclock(args) -> None:
         print(rt.registry.render_text(), end="")
     if srv is not None:
         srv.shutdown()
+    health, _age = rt.check_health()
+    _exit_on_failure(
+        len(rt.failed) + sum(not h.finished for h in streams)
+        + (0 if job.done else 1),
+        health, rt.fatal,
+    )
 
 
 def main() -> None:
@@ -295,6 +362,10 @@ def main() -> None:
     # size for the paged backend (needs >= tp visible devices, §11)
     ap.add_argument("--tp", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
+    # real/wallclock: serve the CPU-sized variant of the architecture
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve ModelConfig.reduced() (the CPU choice) "
+                         "instead of the published widths")
     # wallclock gateway surface (DESIGN.md §15)
     ap.add_argument("--backpressure",
                     choices=["queue-with-timeout", "reject-fast"],
@@ -313,6 +384,10 @@ def main() -> None:
     args = ap.parse_args()
     if args.ttft is None and args.mode != "wallclock":
         args.ttft = 1.5
+    if args.mode != "sim":
+        from repro.launch import compile_cache
+
+        compile_cache.enable()
     {"sim": run_sim, "real": run_real, "wallclock": run_wallclock}[args.mode](
         args
     )

@@ -394,7 +394,7 @@ def cached_attention(
 # ---------------------------------------------------------------------------
 # Paged attention (shared block pool, block-table addressed)
 #
-# The paged cache for one layer is {"k", "v"}: (num_blocks, bs, Hkv, D) —
+# The paged cache for one layer is {"k", "v"}: (num_blocks, Hkv, bs, D) —
 # a slice of the engine-owned shared pool.  Sequences address it through
 # ``block_tables`` (B, M); slot for absolute position p is
 # (table[p // bs], p % bs).  Keys are stored roped, exactly like the
@@ -414,35 +414,23 @@ def cached_attention(
 
 
 def _kv_shard_mesh(pool: Dict[str, jnp.ndarray], mesh):
-    """The mesh to shard this layer's paged attention over, or None.
-
-    Sharding is all-or-nothing per layer, keyed on the POOL's KV-head
-    count: when Hkv doesn't divide the model axis the pool replicates
-    (``pool_pspec``), and q must then stay unsharded too — a head-sharded
-    q feeding the single-program Pallas kernel (a custom call with no SPMD
-    partitioning rule) would fail to partition on a real mesh even though
-    q's own head count divides."""
+    """The mesh to shard this layer's paged attention over, or None when
+    there is nothing to shard (no mesh, or a 1-way ``model`` axis).  The
+    KV heads divide the axis: ``RealEngine`` refuses a mesh whose axis
+    does not, never replicating in silence."""
     if mesh is None or "model" not in mesh.axis_names:
         return None
-    msize = mesh.shape["model"]
-    if msize <= 1 or pool["k"].shape[-2] % msize:
-        return None
-    return mesh
+    return mesh if mesh.shape["model"] > 1 else None
 
 
 def shard_paged_heads(x: jnp.ndarray, mesh, head_axis: int) -> jnp.ndarray:
     """Constrain the (kv-)head axis of ``x`` over the mesh's ``model`` axis.
 
-    No-op when ``mesh`` is None, the axis is absent/size-1, or the head
-    count doesn't divide it (replication keeps numerics exact; see
-    ``distributed.sharding.pool_pspec`` for why head_dim is never the
-    fallback)."""
-    if mesh is None or "model" not in mesh.axis_names:
+    No-op when ``mesh`` is None (see ``distributed.sharding.pool_pspec``
+    for why head_dim is never split)."""
+    if mesh is None:
         return x
-    msize = mesh.shape["model"]
     head_axis = head_axis % x.ndim
-    if msize <= 1 or x.shape[head_axis] % msize:
-        return x
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     spec: list = [None] * x.ndim
@@ -491,9 +479,9 @@ def paged_prefill_attention(
     k_pool, v_pool = write_paged_chunk(
         pool["k"], pool["v"], k, v, block_tables, positions
     )
-    k_pool = shard_paged_heads(k_pool, mesh, 2)
-    v_pool = shard_paged_heads(v_pool, mesh, 2)
-    bs = k_pool.shape[1]
+    k_pool = shard_paged_heads(k_pool, mesh, 1)
+    v_pool = shard_paged_heads(v_pool, mesh, 1)
+    bs = k_pool.shape[2]
     max_ctx = block_tables.shape[1] * bs
     kk = gather_paged(k_pool, block_tables, max_ctx)  # (B, T, Hkv, D)
     vv = gather_paged(v_pool, block_tables, max_ctx)
@@ -521,9 +509,9 @@ def paged_decode_attention(
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """One-token decode against the shared paged pool.
 
-    Dispatches to the Pallas ``paged_attention`` kernel on TPU (shard_mapped
-    over KV heads when a mesh is given) and the ``cache_ops`` jnp oracle on
-    CPU (see ``repro.kernels.ops``).
+    Dispatches to the Pallas ragged kernel's ``Qmax = 1`` case on TPU
+    (shard_mapped over KV heads when a mesh is given) and the ``cache_ops``
+    jnp oracle on CPU (see ``repro.kernels.ops``).
     """
     from repro.kernels import ops as kernel_ops
     from repro.kvcache.cache_ops import append_paged
@@ -538,8 +526,8 @@ def paged_decode_attention(
     k_pool, v_pool = append_paged(
         pool["k"], pool["v"], k[:, 0], v[:, 0], block_tables, positions[:, 0]
     )
-    k_pool = shard_paged_heads(k_pool, mesh, 2)
-    v_pool = shard_paged_heads(v_pool, mesh, 2)
+    k_pool = shard_paged_heads(k_pool, mesh, 1)
+    v_pool = shard_paged_heads(v_pool, mesh, 1)
     out = kernel_ops.paged_attention(
         q[:, 0], k_pool, v_pool, block_tables, positions[:, 0] + 1,
         logit_softcap=cfg.logit_softcap, mesh=mesh,
@@ -613,8 +601,8 @@ def paged_ragged_attention(
     k_pool, v_pool = write_ragged(
         pool["k"], pool["v"], k[0], v[0], meta.dst_row, meta.dst_off
     )
-    k_pool = shard_paged_heads(k_pool, mesh, 2)
-    v_pool = shard_paged_heads(v_pool, mesh, 2)
+    k_pool = shard_paged_heads(k_pool, mesh, 1)
+    v_pool = shard_paged_heads(v_pool, mesh, 1)
     q_pad = jnp.take(q[0], meta.qpad, axis=0)  # (S, Qmax, H, D)
     out = kernel_ops.ragged_paged_attention(
         q_pad, k_pool, v_pool, block_tables, meta.q_pos, meta.kv_lens,

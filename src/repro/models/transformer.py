@@ -168,14 +168,17 @@ def init_paged_pools(
 ) -> Dict[str, PyTree]:
     """Shared physical KV pools, one {"k","v"} pair per pattern position.
 
-    Leaves are (num_periods, num_blocks, block_size, Hkv, D) — the same
+    Leaves are (num_periods, num_blocks, Hkv, block_size, D) — the same
     period-major stacking as params/caches, so the period scan and the
-    segment slicing helpers apply unchanged.  Every resident sequence lives
-    in these pools, addressed via block tables of physical block ids."""
+    segment slicing helpers apply unchanged; KV heads sit outside the
+    block so the attention kernel loads one head's page as a
+    ``(block_size, D)`` tile (``kernels.paged_attention``).  Every resident
+    sequence lives in these pools, addressed via block tables of physical
+    block ids."""
     if not supports_paged(cfg):
         raise ValueError(f"{cfg.name}: paged pools require plain causal KV")
     hd = cfg.resolved_head_dim
-    shape = (cfg.num_periods, num_blocks, block_size, cfg.num_kv_heads, hd)
+    shape = (cfg.num_periods, num_blocks, cfg.num_kv_heads, block_size, hd)
     return {
         str(i): {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
         for i, _ in enumerate(cfg.layer_pattern())

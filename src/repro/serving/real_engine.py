@@ -10,10 +10,10 @@ recompute path are exact.
 
 Implementation notes:
 * Physical KV layout is the *paged* shared pool (DESIGN.md §5): per-layer
-  pools ``(num_device_blocks+1, block_size, Hkv, D)`` addressed via block
-  tables built from the BlockManager's physical block ids; decode dispatches
-  to the Pallas ``paged_attention`` kernel on TPU and the ``cache_ops`` jnp
-  oracle on CPU.  The last pool row is a scratch block that absorbs writes
+  pools ``(num_device_blocks+1, Hkv, block_size, D)`` addressed via block
+  tables built from the BlockManager's physical block ids; attention
+  dispatches to the Pallas ``ragged_paged_attention`` kernel on TPU and the
+  ``cache_ops`` jnp oracle on CPU.  The last pool row is a scratch block that absorbs writes
   from padded batch rows.
 * Fused mixed-batch execution (DESIGN.md §12, the default paged hot
   path, ``RealEngineConfig.fused_batch``): the whole ``IterationPlan`` —
@@ -92,9 +92,9 @@ from repro.core.profiler import (
     BatchShape,
     CalibrationGrid,
     MeasuredProfiler,
-    TPU_V5E,
     block_bytes,
     calibrate,
+    hardware_for,
 )
 from repro.core.request import Request
 from repro.core.faults import InjectedFault, RequestFailed
@@ -251,13 +251,20 @@ class RealEngine:
             sched_cfg = dataclasses.replace(
                 sched_cfg, max_model_len=eng_cfg.max_model_len
             )
-        lat = AnalyticalCostModel(cfg, TPU_V5E)  # until calibrate() replaces it
+        # until calibrate() replaces it; peaks keyed by the chip's kind
+        lat = AnalyticalCostModel(cfg, hardware_for(jax.devices()[0]))
         self.sched = UnifiedScheduler(cfg, lat, slo, self.blocks, sched_cfg)
 
         self.mesh = eng_cfg.mesh
         if self.mesh is not None:
             if "model" not in self.mesh.axis_names:
                 raise ValueError("serving mesh needs a 'model' axis")
+            tp = self.mesh.shape["model"]
+            if cfg.num_kv_heads % tp:
+                raise ValueError(
+                    f"{cfg.name}: {cfg.num_kv_heads} KV heads do not divide "
+                    f"the {tp}-way model axis"
+                )
             if not self.paged:
                 raise ValueError(
                     "tensor-parallel serving requires the paged backend "
@@ -383,52 +390,57 @@ class RealEngine:
                 ]
                 self.pools = None
 
-            def _decode_paged(last, pools, tables, lens):
+            # Every model program takes the weights as its first traced
+            # argument and never closes over them: a closed-over array
+            # would be baked into the program as a constant (gigabytes per
+            # compiled bucket at published widths).
+            def _decode_paged(params, last, pools, tables, lens):
                 self.decode_trace_count += 1  # runs only while tracing
                 return tf.decode_step_paged(
-                    self.cfg, self.params, last, pools, tables, lens,
+                    self.cfg, params, last, pools, tables, lens,
                     mesh=self.mesh,
                 )
 
-            self._decode_jit = jax.jit(_decode_paged, donate_argnums=(1,))
+            self._decode_jit = jax.jit(_decode_paged, donate_argnums=(2,))
 
-            def _prefill_paged(toks, pools, tables, off, last):
+            def _prefill_paged(params, toks, pools, tables, off, last):
                 self.prefill_trace_count += 1  # runs only while tracing
                 return tf.prefill_chunk_paged(
-                    self.cfg, self.params, toks, pools, tables, off,
+                    self.cfg, params, toks, pools, tables, off,
                     last_index=last, mesh=self.mesh,
                 )
 
-            self._prefill_jit = jax.jit(_prefill_paged, donate_argnums=(1,))
+            self._prefill_jit = jax.jit(_prefill_paged, donate_argnums=(2,))
             # traced-start segment program: all equal-length segments share
             # one compilation per batch bucket (run_segment_paged_at)
             self._segment_jit = jax.jit(
-                lambda pps, lo, x, pools, tables, positions: (
+                lambda pps, params, lo, x, pools, tables, positions: (
                     tf.run_segment_paged_at(
-                        self.cfg, self.params, pps, lo, x, pools, tables,
+                        self.cfg, params, pps, lo, x, pools, tables,
                         positions, mesh=self.mesh,
                     )
                 ),
                 static_argnums=(0,),
-                donate_argnums=(3,),
+                donate_argnums=(4,),
             )
 
             # fused ragged token-batch programs (DESIGN.md §12): one
             # traced-start segment shared by all equal-length segments of
             # every (token, sequence, query-length) bucket triple, plus
             # the S-row logits gather
-            def _fused_segment(pps, lo, x, pools, tables, positions, meta):
+            def _fused_segment(pps, params, lo, x, pools, tables, positions,
+                               meta):
                 self.fused_trace_count += 1  # runs only while tracing
                 return tf.run_tokens_paged_at(
-                    self.cfg, self.params, pps, lo, x, pools, tables,
+                    self.cfg, params, pps, lo, x, pools, tables,
                     positions, meta, mesh=self.mesh,
                 )
 
             self._fused_segment_jit = jax.jit(
-                _fused_segment, static_argnums=(0,), donate_argnums=(3,)
+                _fused_segment, static_argnums=(0,), donate_argnums=(4,)
             )
             self._fused_logits_jit = jax.jit(
-                lambda x, li: tf.ragged_lm_head(self.cfg, self.params, x, li)
+                lambda params, x, li: tf.ragged_lm_head(self.cfg, params, x, li)
             )
 
             # pipelined-engine programs (DESIGN.md §13): the per-segment
@@ -451,16 +463,16 @@ class RealEngine:
             # the host enqueues — in-place updates AND real overlap.  The
             # displaced slice references are parked in ``_retired`` until
             # their holds provably resolved (see _drop_retired).
-            def _fused_segment_seg(pps, lo, x, pool_seg, tables, positions,
-                                   meta):
+            def _fused_segment_seg(pps, params, lo, x, pool_seg, tables,
+                                   positions, meta):
                 self.fused_trace_count += 1  # runs only while tracing
                 return tf.run_tokens_paged_seg(
-                    self.cfg, self.params, pps, lo, x, pool_seg, tables,
+                    self.cfg, params, pps, lo, x, pool_seg, tables,
                     positions, meta, mesh=self.mesh,
                 )
 
             self._fused_segment_seg_jit = jax.jit(
-                _fused_segment_seg, static_argnums=(0,), donate_argnums=(3,)
+                _fused_segment_seg, static_argnums=(0,), donate_argnums=(4,)
             )
 
             def _extract_segs(segs, ids):
@@ -590,25 +602,25 @@ class RealEngine:
         else:
             self.caches: Dict[int, Any] = {}  # request_id -> cache pytree (B=1)
 
-            def _decode(last, caches, lens):
+            def _decode(params, last, caches, lens):
                 self.decode_trace_count += 1  # runs only while tracing
-                return tf.decode_step(self.cfg, self.params, last, caches, lens)
+                return tf.decode_step(self.cfg, params, last, caches, lens)
 
             self._decode_jit = jax.jit(
                 _decode,
-                donate_argnums=(1,),  # in-place cache update (TPU semantics)
+                donate_argnums=(2,),  # in-place cache update (TPU semantics)
             )
             self._segment_jit = jax.jit(
-                lambda seg, x, caches, positions: tf.run_segment(
-                    self.cfg, self.params, seg, x, caches,
+                lambda seg, params, x, caches, positions: tf.run_segment(
+                    self.cfg, params, seg, x, caches,
                     mode="decode", positions=positions,
                 ),
                 static_argnums=(0,),
-                donate_argnums=(2,),
+                donate_argnums=(3,),
             )
             self._prefill_jit = jax.jit(
-                lambda toks, caches, off, img: tf.prefill_chunk(
-                    self.cfg, self.params, toks, caches, off, image_embeds=img
+                lambda params, toks, caches, off, img: tf.prefill_chunk(
+                    self.cfg, params, toks, caches, off, image_embeds=img
                 )
             )
 
@@ -1239,7 +1251,8 @@ class RealEngine:
                 idx["i"] += 1
                 old = self._pool_segs[i]
                 h, self._pool_segs[i] = self._fused_segment_seg_jit(
-                    pps, np.int32(lo), h, old, tables, positions, meta
+                    pps, self.params, np.int32(lo), h, old, tables,
+                    positions, meta,
                 )
                 self._retired.append((h, old))
                 return h
@@ -1249,14 +1262,15 @@ class RealEngine:
             if aborted:
                 return None, True
             self.dispatches["fused_logits"] += 1
-            logits = self._fused_logits_jit(x, logit_idx)
+            logits = self._fused_logits_jit(self.params, x, logit_idx)
             self._drop_retired()
             return logits, False
         else:
 
             def seg(lo, pps, h):
                 h, self.pools = self._fused_segment_jit(
-                    pps, np.int32(lo), h, self.pools, tables, positions, meta
+                    pps, self.params, np.int32(lo), h, self.pools, tables,
+                    positions, meta,
                 )
                 return h
 
@@ -1265,7 +1279,7 @@ class RealEngine:
             if aborted:
                 return None, True
         self.dispatches["fused_logits"] += 1
-        return self._fused_logits_jit(x, logit_idx), False
+        return self._fused_logits_jit(self.params, x, logit_idx), False
 
     def _build_fused(self, plan) -> Tuple[List[tuple], tuple]:
         """Lower an ``IterationPlan`` to device-ready fused inputs.
@@ -1601,6 +1615,7 @@ class RealEngine:
                 last[i] = c.length - 1
             self.dispatches["prefill"] += 1
             logits, self.pools = self._prefill_jit(
+                self.params,
                 self._put(toks),
                 self.pools,
                 self._put(tables),
@@ -1648,6 +1663,7 @@ class RealEngine:
             img = getattr(r, "image_embeds", None)
             img = img if (img is not None and chunk.offset == 0) else None
             logits, cache = self._prefill_jit(
+                self.params,
                 jnp.asarray(toks)[None, :],
                 self.caches[rid],
                 jnp.array([chunk.offset], jnp.int32),
@@ -1684,7 +1700,7 @@ class RealEngine:
         else:
             self.dispatches["decode"] += 1
             logits, self.pools = self._decode_jit(
-                last_j, self.pools, tables_j, lens_j
+                self.params, last_j, self.pools, tables_j, lens_j
             )
         return logits[:bsz], False
 
@@ -1698,7 +1714,8 @@ class RealEngine:
 
         def seg(lo, pps, h):
             h, self.pools = self._segment_jit(
-                pps, np.int32(lo), h, self.pools, tables, positions
+                pps, self.params, np.int32(lo), h, self.pools, tables,
+                positions,
             )
             return h
 
@@ -1720,7 +1737,9 @@ class RealEngine:
             if aborted:
                 return None, True
         else:
-            logits, stacked = self._decode_jit(last, stacked, lens)
+            logits, stacked = self._decode_jit(
+                self.params, last, stacked, lens
+            )
         for i, r in enumerate(reqs):
             self.caches[r.request_id] = jax.tree.map(
                 lambda x, i=i: x[:, i : i + 1], stacked
@@ -1738,7 +1757,7 @@ class RealEngine:
         def make_seg(i):
             def run():
                 state["x"], state["caches"] = self._segment_jit(
-                    i, state["x"], state["caches"], positions
+                    i, self.params, state["x"], state["caches"], positions
                 )
 
             return run
@@ -1854,18 +1873,18 @@ class RealEngine:
                             old = self._pool_segs[i]
                             x, self._pool_segs[i] = (
                                 self._fused_segment_seg_jit(
-                                    pps, np.int32(lo), x, old, tables,
-                                    positions, meta,
+                                    pps, self.params, np.int32(lo), x, old,
+                                    tables, positions, meta,
                                 )
                             )
                             self._retired.append((x, old))
                     else:
                         for lo, pps in spans:
                             x, self.pools = self._fused_segment_jit(
-                                pps, np.int32(lo), x, self.pools, tables,
-                                positions, meta,
+                                pps, self.params, np.int32(lo), x,
+                                self.pools, tables, positions, meta,
                             )
-                    out = self._fused_logits_jit(x, li)
+                    out = self._fused_logits_jit(self.params, x, li)
                     if block:
                         jax.block_until_ready(out)
                         self._drop_retired()
@@ -1943,7 +1962,7 @@ class RealEngine:
 
                 def once():
                     logits, self.pools = self._prefill_jit(
-                        toks, self.pools, table, off, last
+                        self.params, toks, self.pools, table, off, last
                     )
                     jax.block_until_ready(logits)
 
@@ -1961,14 +1980,14 @@ class RealEngine:
                 x = tf.embed(self.cfg, self.params, last[:, None])
                 for lo, pps in tf.segment_spans(self.cfg):
                     x, self.pools = self._segment_jit(
-                        pps, np.int32(lo), x, self.pools, tables,
-                        lens[:, None],
+                        pps, self.params, np.int32(lo), x, self.pools,
+                        tables, lens[:, None],
                     )
                 jax.block_until_ready(x)
 
                 def once():
                     logits, self.pools = self._decode_jit(
-                        last, self.pools, tables, lens
+                        self.params, last, self.pools, tables, lens
                     )
                     jax.block_until_ready(logits)
 
@@ -1989,7 +2008,8 @@ class RealEngine:
 
                 def once():
                     logits, _ = self._prefill_jit(
-                        toks, tf.init_caches(self.cfg, 1, max_ctx), off, None
+                        self.params, toks, tf.init_caches(self.cfg, 1, max_ctx),
+                        off, None,
                     )
                     jax.block_until_ready(logits)
 
@@ -2001,7 +2021,8 @@ class RealEngine:
 
                 def once():
                     logits, _ = self._decode_jit(
-                        last, tf.init_caches(self.cfg, b, max_ctx), lens
+                        self.params, last, tf.init_caches(self.cfg, b, max_ctx),
+                        lens,
                     )
                     jax.block_until_ready(logits)
 

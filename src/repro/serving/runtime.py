@@ -234,6 +234,12 @@ class CoServingRuntime:
     def health(self) -> RuntimeHealth:
         return self._health
 
+    @property
+    def fatal(self) -> Optional[EngineDead]:
+        """The sticky engine-fatal error (its ``traceback_text`` holds the
+        engine thread's traceback), or None while the engine is alive."""
+        return self._fatal
+
     def check_health(self) -> Tuple[RuntimeHealth, float]:
         """(health, heartbeat age in seconds) — the ``/health`` endpoint
         surface.  Safe from any thread; also detects an engine thread that

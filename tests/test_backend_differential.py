@@ -81,8 +81,14 @@ def _run(arch, backend, jobs, preempt_step, mesh=None, eng_kw=None):
     return [r.output_tokens for r in reqs], [r.output_tokens for r in online], reqs
 
 
-def _tp() -> int:
-    return min(4, len(jax.devices()))
+def _tp(kv_heads: int = 4) -> int:
+    """Largest mesh size <= 4 visible devices that divides the KV heads (a
+    non-dividing model axis is a construction error, never a silent
+    replication)."""
+    tp = min(4, len(jax.devices()))
+    while kv_heads % tp:
+        tp -= 1
+    return tp
 
 
 # (arch, [(prompt_len, max_new), ...], preempt_step, engine kwargs)
@@ -96,8 +102,8 @@ CASES = [
     ("llama-2-7b", [(40, 16)] * 3, 6, dict(num_device_blocks=14)),
     # same burst landing during the prefill wave
     ("llama-2-7b", [(40, 16)] * 3, 2, dict(num_device_blocks=14)),
-    # GQA arch (4Q/2KV heads): on a 4-way mesh the pool replicates (2 % 4)
-    # while the query heads still shard — the mixed layout must stay exact
+    # GQA arch (4Q/2KV heads, groups of 2): the sharded leg splits the 2 KV
+    # heads over at most 2 devices, each keeping its whole query group
     ("qwen2-0.5b", [(40, 8), (20, 8)], None, {}),
     ("qwen2-0.5b", [(40, 10), (24, 6), (40, 6), (20, 4)], 4,
      dict(num_device_blocks=14)),
@@ -114,8 +120,9 @@ def test_backends_emit_identical_tokens(arch, jobs, preempt_step, eng_kw):
                                eng_kw=eng_kw)
     out_l, on_l, reqs_l = _run(arch, "paged", jobs, preempt_step,
                                eng_kw=dict(eng_kw, pipeline=True))
+    mesh = make_serving_mesh(_tp(_model(arch)[0].num_kv_heads))
     out_s, on_s, reqs_s = _run(arch, "paged", jobs, preempt_step,
-                               mesh=make_serving_mesh(_tp()), eng_kw=eng_kw)
+                               mesh=mesh, eng_kw=eng_kw)
     assert [len(o) for o in out_p] == [g for _, g in jobs]
     assert out_p == out_c, "split paged backend diverged from contiguous"
     assert out_f == out_p, "fused ragged path diverged from split paged"
@@ -349,10 +356,9 @@ def test_shared_prefix_mid_iteration_abort_is_exact():
 
 
 def test_sharded_pool_is_actually_sharded():
-    """With a dividing mesh, the MHA pool must shard its KV-head axis (the
-    memory win tensor parallelism exists for); otherwise (1 device, or an
-    odd virtual-device count that doesn't divide Hkv) the mesh leg must
-    still run with the deliberate replication fallback."""
+    """On a multi-device mesh the MHA pool must shard its KV-head axis
+    (the memory win tensor parallelism exists for), each shard holding
+    Hkv/tp heads; a 1-device mesh leaves it whole."""
     cfg, params = _model("llama-2-7b")
     tp = _tp()
     eng = RealEngine(
@@ -360,12 +366,27 @@ def test_sharded_pool_is_actually_sharded():
         eng_cfg=RealEngineConfig(backend="paged", mesh=make_serving_mesh(tp)),
     )
     spec = eng.pools["0"]["k"].sharding.spec
-    if tp > 1 and cfg.num_kv_heads % tp == 0:
-        assert spec[3] == "model", spec
+    if tp > 1:
+        assert spec[2] == "model", spec
         shard = next(iter(eng.pools["0"]["k"].addressable_shards))
-        assert shard.data.shape[3] == cfg.num_kv_heads // tp
+        assert shard.data.shape[2] == cfg.num_kv_heads // tp
     else:
         assert all(s is None for s in spec)
+
+
+def test_mesh_must_divide_kv_heads():
+    """A model axis that does not divide the KV heads is refused when the
+    engine is built, before anything is placed on a device."""
+    from jax.sharding import AbstractMesh
+
+    cfg, params = _model("qwen2-0.5b")  # 2 KV heads
+    with pytest.raises(ValueError, match="do not divide"):
+        RealEngine(
+            cfg, params,
+            eng_cfg=RealEngineConfig(
+                mesh=AbstractMesh((1, 3), ("data", "model"))
+            ),
+        )
 
 
 def test_mesh_requires_paged_backend():

@@ -5,19 +5,15 @@ match ref.py to float tolerance.  Larger shapes run on TPU only; interpret
 mode is slow, so sweeps stay compact but cover GQA groups, ragged tails,
 sliding windows, chunk offsets, and dtypes.
 """
-import os
-
 import jax
 import jax.numpy as jnp
 import pytest
 from _hypothesis_compat import given, settings, strategies as st
 
-os.environ.setdefault("REPRO_KERNEL_BACKEND", "interpret")
-
-from repro.kernels import ops, ref  # noqa: E402
-from repro.kernels.flash_attention import flash_attention  # noqa: E402
-from repro.kernels.kv_checkpoint import checkpoint_gather, checkpoint_scatter  # noqa: E402
-from repro.kernels.paged_attention import paged_attention  # noqa: E402
+from repro.kernels import ops, ref
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.kv_checkpoint import checkpoint_gather, checkpoint_scatter
+from repro.kernels.paged_attention import paged_attention
 
 KEY = jax.random.PRNGKey(0)
 
@@ -71,8 +67,8 @@ PAGED_CASES = [
 def test_paged_attention_matches_ref(case):
     b, h, hkv, d, page, npages, m = case
     q = _rand((b, h, d), jnp.float32, 4)
-    kp = _rand((npages, page, hkv, d), jnp.float32, 5)
-    vp = _rand((npages, page, hkv, d), jnp.float32, 6)
+    kp = _rand((npages, hkv, page, d), jnp.float32, 5)
+    vp = _rand((npages, hkv, page, d), jnp.float32, 6)
     key = jax.random.fold_in(KEY, 7)
     # random non-overlapping page assignment with ragged lengths
     perm = jax.random.permutation(key, npages)[: b * m].reshape(b, m)
@@ -96,8 +92,8 @@ def test_paged_attention_matches_ref(case):
 def test_paged_attention_property(b, g, hkv, page, m):
     h, d, npages = hkv * g, 32, 24
     q = _rand((b, h, d), jnp.float32, 10)
-    kp = _rand((npages, page, hkv, d), jnp.float32, 11)
-    vp = _rand((npages, page, hkv, d), jnp.float32, 12)
+    kp = _rand((npages, hkv, page, d), jnp.float32, 11)
+    vp = _rand((npages, hkv, page, d), jnp.float32, 12)
     key = jax.random.fold_in(KEY, 13)
     perm = jax.random.permutation(key, npages)[: b * m].reshape(b, m)
     lens = jax.random.randint(jax.random.fold_in(KEY, 14), (b,), 1, m * page + 1)
@@ -114,6 +110,7 @@ RAGGED_CASES = [
     ([8, 1, 4, 1], 4, 2, 32, 8, 6),  # mixed prefill chunks + decodes
     ([6, 3], 4, 4, 32, 8, 4),  # dense (g = 1) ragged chunks
     ([5, 1], 16, 1, 64, 16, 3),  # MQA
+    ([130, 1], 2, 1, 32, 16, 10),  # Qmax > QUERY_TILE: tiled, padded rows
 ]
 
 
@@ -132,8 +129,8 @@ def test_ragged_paged_attention_matches_ref(case, softcap):
     qmax = max(q_lens)
     npages = s * m
     q = _rand((s, qmax, h, d), jnp.float32, 40)
-    kp = _rand((npages, page, hkv, d), jnp.float32, 41)
-    vp = _rand((npages, page, hkv, d), jnp.float32, 42)
+    kp = _rand((npages, hkv, page, d), jnp.float32, 41)
+    vp = _rand((npages, hkv, page, d), jnp.float32, 42)
     key = jax.random.fold_in(KEY, 43)
     perm = jax.random.permutation(key, npages)[: s * m].reshape(s, m)
     kv_lens = jax.random.randint(
@@ -163,8 +160,8 @@ def test_ragged_kernel_decode_degenerates_to_paged_attention():
 
     b, h, hkv, d, page, m, npages = 2, 8, 2, 64, 16, 3, 8
     q = _rand((b, h, d), jnp.float32, 50)
-    kp = _rand((npages, page, hkv, d), jnp.float32, 51)
-    vp = _rand((npages, page, hkv, d), jnp.float32, 52)
+    kp = _rand((npages, hkv, page, d), jnp.float32, 51)
+    vp = _rand((npages, hkv, page, d), jnp.float32, 52)
     perm = jax.random.permutation(jax.random.fold_in(KEY, 53), npages)[
         : b * m
     ].reshape(b, m)
@@ -181,14 +178,14 @@ def test_ragged_kernel_decode_degenerates_to_paged_attention():
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_checkpoint_gather_matches_ref(dtype):
-    pool = _rand((32, 16, 2, 64), dtype, 20)
+    pool = _rand((32, 2, 16, 64), dtype, 20)
     ids = jnp.array([5, 2, 17, 9, 31], jnp.int32)
     out = checkpoint_gather(pool, ids, interpret=True)
     assert jnp.array_equal(out, ref.checkpoint_gather_ref(pool, ids))
 
 
 def test_checkpoint_scatter_roundtrip():
-    pool = _rand((32, 16, 2, 64), jnp.float32, 21)
+    pool = _rand((32, 2, 16, 64), jnp.float32, 21)
     ids = jnp.array([3, 8, 1], jnp.int32)
     staged = checkpoint_gather(pool, ids, interpret=True)
     wiped = pool.at[ids].set(0.0)
@@ -196,9 +193,16 @@ def test_checkpoint_scatter_roundtrip():
     assert jnp.array_equal(restored, pool)
 
 
-def test_ops_dispatch_ref_backend():
+def test_ops_dispatch_ref_backend(monkeypatch):
     # default CPU backend = jnp reference (no pallas); smoke the dispatcher
-    assert ops.kernel_backend() in ("ref", "interpret", "pallas")
+    monkeypatch.delenv("REPRO_KERNEL_BACKEND", raising=False)
+    assert ops.kernel_backend() == "ref"
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "interpret")
+    assert ops.kernel_backend() == "interpret"
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "pallas")
+    with pytest.raises(ValueError):  # compiled kernels need the chip
+        ops.kernel_backend()
+    monkeypatch.delenv("REPRO_KERNEL_BACKEND")
     q = _rand((1, 8, 4, 16), jnp.float32, 30)
     k = _rand((1, 8, 2, 16), jnp.float32, 31)
     v = _rand((1, 8, 2, 16), jnp.float32, 32)

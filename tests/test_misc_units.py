@@ -113,3 +113,21 @@ def test_stream_handle_incremental_poll():
     assert h.poll() == []
     r.output_tokens.append(9)
     assert h.poll() == [9]
+
+
+@pytest.mark.parametrize("platform,kind,expect", [
+    ("cpu", "cpu", "tpu-v5e"),  # off the chip: the planning target
+    ("tpu", "TPU v5 lite", "tpu-v5e"),
+    ("tpu", "TPU v9 imaginary", None),  # unknown chip: an error
+])
+def test_hardware_for_keys_peaks_by_device_kind(platform, kind, expect):
+    from types import SimpleNamespace
+
+    from repro.core.profiler import hardware_for
+
+    dev = SimpleNamespace(platform=platform, device_kind=kind)
+    if expect is None:
+        with pytest.raises(ValueError, match="no peak table"):
+            hardware_for(dev)
+    else:
+        assert hardware_for(dev).name == expect

@@ -88,8 +88,8 @@ def test_write_paged_chunk_matches_append_order():
     would."""
     key = jax.random.PRNGKey(2)
     bs, nblk, hkv, d = 4, 8, 2, 16
-    k_pool = jnp.zeros((nblk, bs, hkv, d))
-    v_pool = jnp.zeros((nblk, bs, hkv, d))
+    k_pool = jnp.zeros((nblk, hkv, bs, d))
+    v_pool = jnp.zeros((nblk, hkv, bs, d))
     tables = jnp.array([[5, 2, 7, -1], [1, 6, -1, -1]], jnp.int32)
     k_new = jax.random.normal(key, (2, 6, hkv, d))
     v_new = jax.random.normal(jax.random.fold_in(key, 1), (2, 6, hkv, d))
@@ -109,8 +109,8 @@ def test_write_paged_chunk_matches_append_order():
 def test_scatter_drops_writes_through_padding():
     """Writes addressed through -1 table entries (or beyond the table) must
     be dropped, never aliased onto a real block."""
-    k_pool = jnp.zeros((4, 2, 1, 4))
-    v_pool = jnp.zeros((4, 2, 1, 4))
+    k_pool = jnp.zeros((4, 1, 2, 4))
+    v_pool = jnp.zeros((4, 1, 2, 4))
     tables = jnp.array([[2, -1]], jnp.int32)
     ones = jnp.ones((1, 1, 1, 4))
     # token at position 3 -> padded column 1 -> dropped
@@ -156,8 +156,8 @@ def test_paged_attention_ref_softcap():
 
     key = jax.random.PRNGKey(4)
     q = jax.random.normal(key, (2, 4, 32))
-    kp = jax.random.normal(jax.random.fold_in(key, 1), (8, 8, 2, 32))
-    vp = jax.random.normal(jax.random.fold_in(key, 2), (8, 8, 2, 32))
+    kp = jax.random.normal(jax.random.fold_in(key, 1), (8, 2, 8, 32))
+    vp = jax.random.normal(jax.random.fold_in(key, 2), (8, 2, 8, 32))
     tables = jnp.array([[0, 3, 6], [1, 4, -1]], jnp.int32)
     lens = jnp.array([20, 11], jnp.int32)
     out = paged_attention(
@@ -513,3 +513,34 @@ def test_prefix_retrace_regression_guard_shared_drain():
     ), "prefix_tokens_saved disagrees with per-request attribution"
     assert all(len(r.output_tokens) == g for r, (_, g, _s) in
                zip(reqs, specs))
+
+
+def test_fused_segment_takes_weights_as_arguments():
+    """The fused segment program receives the weights as arguments and
+    bakes none of them in as constants (a closed-over array would become a
+    constant of every compiled bucket — gigabytes at published widths)."""
+    import re
+
+    cfg = get_config("qwen2-0.5b").reduced()
+    params = tf.init_params(cfg, jax.random.PRNGKey(0))
+    eng = RealEngine(cfg, params, eng_cfg=RealEngineConfig(backend="paged"))
+    toks, tables, positions, meta, _li = eng._fused_inputs(
+        eng._build_ragged([(4, 0, None, None), (1, 7, None, None)])
+    )
+    lo, pps = tf.segment_spans(cfg)[0]
+    x = tf.embed(cfg, params, toks[None])
+    lowered = eng._fused_segment_jit.lower(
+        pps, eng.params, np.int32(lo), x, eng.pools, tables, positions, meta
+    )
+    weights = jax.tree.leaves(lowered.args_info[0][0])  # pps is static
+    assert len(weights) == len(jax.tree.leaves(params))
+    const_sizes = [
+        int(np.prod([int(d) for d in dims.split("x")]))
+        for dims in re.findall(
+            r"stablehlo\.constant dense<[^>]*>\s*:\s*tensor<((?:\d+x)+)\w+>",
+            lowered.as_text(),
+        )
+        for dims in [dims.rstrip("x")]
+    ]
+    # the smallest weight is a d_model-wide norm vector
+    assert max(const_sizes, default=0) < cfg.d_model, const_sizes

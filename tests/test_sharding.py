@@ -14,8 +14,9 @@ from repro.launch.hlo_analysis import parse_hlo, rollup, trip_of
 
 
 def _abstract_mesh(shape, axes):
-    # Installed JAX takes ((name, size), ...) pairs, not (shape, axes).
-    return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    # jax.sharding.AbstractMesh(axis_sizes, axis_names) — the JAX >= 0.5
+    # signature (the installed 0.9.0 rejects (name, size) pairs).
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
 
 
 @pytest.mark.parametrize("arch", ["command-r-plus-104b", "mixtral-8x22b",
