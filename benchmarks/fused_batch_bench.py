@@ -17,7 +17,8 @@ Per leg it reports:
   * device dispatches of the jitted model programs per engine
     (`RealEngine.dispatches`) and jit trace counts,
   * per-iteration latency p50/p99,
-  * host-gap p50/p99 (fused legs): per-iteration device-idle time — the
+  * host-gap p50/p99 (fused legs; bucket midpoints of the engine's
+    ``host_gap`` histogram): per-iteration device-idle time — the
     serial host span (sample readback, commit, plan, batch build) during
     which the device queue is empty, which the pipeline exists to kill,
   * byte-identical greedy tokens across all legs (hard assert — a kernel
@@ -44,6 +45,7 @@ from repro.configs import get_config
 from repro.core.request import Priority, Request
 from repro.core.scheduler import SchedulerConfig
 from repro.models import transformer as tf
+from repro.serving.metrics import Histogram
 from repro.serving.real_engine import RealEngine, RealEngineConfig
 
 
@@ -111,7 +113,8 @@ def _bench(cfg, params, smoke: bool, fused: bool, pipeline: bool = False):
     _drive(eng, *_workload(cfg, smoke))
     d0 = dict(eng.dispatches)
     steps0 = eng.steps
-    gaps0 = len(eng.host_gap_s)
+    # the timed pass's host gaps alone
+    eng.host_gap = Histogram(eng.host_gap.name, eng.host_gap.bounds)
     t0 = time.perf_counter()
     outs, ntok, iters = _drive(eng, *_workload(cfg, smoke))
     dt = time.perf_counter() - t0
@@ -133,10 +136,9 @@ def _bench(cfg, params, smoke: bool, fused: bool, pipeline: bool = False):
             "pipeline": eng.pipeline_trace_count,
         },
     }
-    gaps_ms = np.asarray(eng.host_gap_s[gaps0:]) * 1e3
-    if gaps_ms.size:  # fused legs only (the split path never samples gaps)
-        stats["host_gap_p50_ms"] = round(float(np.percentile(gaps_ms, 50)), 3)
-        stats["host_gap_p99_ms"] = round(float(np.percentile(gaps_ms, 99)), 3)
+    if eng.host_gap.count:  # fused legs only (the split path never samples gaps)
+        stats["host_gap_p50_ms"] = round(eng.host_gap.percentile(50) * 1e3, 3)
+        stats["host_gap_p99_ms"] = round(eng.host_gap.percentile(99) * 1e3, 3)
     if pipeline:
         stats["pipeline_discards"] = eng.pipeline_discards
     return outs, stats

@@ -20,12 +20,53 @@ Histograms use fixed bucket bounds chosen at registration, a bisect per
 observe, and expose count/sum plus approximate percentiles reconstructed
 from bucket midpoints — enough for TTFT/TPOT dashboards without keeping
 unbounded sample lists on the engine thread.
+
+The program's profiler spans are named here too (``SPAN_*``, one shared
+prefix): ``serving/runtime.py`` and ``serving/real_engine.py`` open them
+as ``jax.profiler.TraceAnnotation`` at every layer boundary of the engine
+loop, under one ``StepTraceAnnotation`` root per loop turn.  They land in
+the profiler's host plane on the device trace's clock, and cost about a
+microsecond each when no profiler runs (DESIGN.md §15).
 """
 from __future__ import annotations
 
 import threading
 from bisect import bisect_left
 from typing import Dict, List, Optional, Sequence, Tuple
+
+SPAN_PREFIX = "conserve."
+# one turn of the engine loop (the root): stats step_num, t_host, tokens
+SPAN_ITERATION = SPAN_PREFIX + "runtime.iteration"
+SPAN_DRAIN = SPAN_PREFIX + "runtime.drain"  # arrivals delivered
+SPAN_PUMP = SPAN_PREFIX + "runtime.pump"
+SPAN_PUBLISH = SPAN_PREFIX + "runtime.publish"
+SPAN_IDLE = SPAN_PREFIX + "runtime.idle"  # the loop's sleep: no work
+SPAN_BACKOFF = SPAN_PREFIX + "runtime.backoff"  # work, none schedulable
+SPAN_STEP = SPAN_PREFIX + "engine.step"
+SPAN_PLAN = SPAN_PREFIX + "sched.plan"
+SPAN_COMMIT = SPAN_PREFIX + "sched.commit"
+SPAN_EVENTS = SPAN_PREFIX + "engine.events"
+SPAN_BUILD = SPAN_PREFIX + "engine.build"  # host arrays of a fused batch
+SPAN_PUT = SPAN_PREFIX + "engine.put"  # their transfers to the device
+SPAN_DISPATCH = SPAN_PREFIX + "engine.dispatch"
+SPAN_SAMPLE = SPAN_PREFIX + "engine.sample"
+SPAN_SWAP_OUT = SPAN_PREFIX + "kv.swap_out"
+SPAN_COW = SPAN_PREFIX + "kv.cow"
+SPAN_RESTORE = SPAN_PREFIX + "kv.restore"
+SPAN_CHECKPOINT = SPAN_PREFIX + "kv.checkpoint"
+SPANS = (
+    SPAN_ITERATION, SPAN_DRAIN, SPAN_PUMP, SPAN_PUBLISH, SPAN_IDLE,
+    SPAN_BACKOFF, SPAN_STEP, SPAN_PLAN, SPAN_COMMIT, SPAN_EVENTS, SPAN_BUILD,
+    SPAN_PUT, SPAN_DISPATCH, SPAN_SAMPLE, SPAN_SWAP_OUT, SPAN_COW,
+    SPAN_RESTORE, SPAN_CHECKPOINT,
+)
+
+# Per-iteration host gap (RealEngine.host_gap): zero when the device never
+# idled, then microseconds to a second.
+HOST_GAP_BOUNDS = (
+    0.0, 1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 0.001, 0.0025, 0.005,
+    0.01, 0.025, 0.05, 0.1, 0.25, 1.0,
+)
 
 
 class Counter:
@@ -156,6 +197,12 @@ class MetricsRegistry:
                     name, Histogram(name, bounds or _DEFAULT_BOUNDS)
                 )
         return h
+
+    def adopt(self, h: Histogram) -> None:
+        """Publish a histogram another object writes (the engine's host
+        gap) under its own name."""
+        with self._lock:
+            self._histograms[h.name] = h
 
     def snapshot(self) -> Dict[str, float]:
         """Flat point-in-time view.  Per-value reads are atomic; there is no

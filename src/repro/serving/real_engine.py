@@ -79,6 +79,7 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.budget import pow2_bucket
 from repro.core.checkpoint import (
@@ -105,6 +106,22 @@ from repro.kvcache.block_manager import BlockManager
 from repro.models import transformer as tf
 from repro.models.config import ModelConfig
 from repro.models.sampling import SamplingParams, sample, sample_rows
+from repro.serving.metrics import (
+    HOST_GAP_BOUNDS,
+    SPAN_BUILD,
+    SPAN_CHECKPOINT,
+    SPAN_COMMIT,
+    SPAN_COW,
+    SPAN_DISPATCH,
+    SPAN_EVENTS,
+    SPAN_PLAN,
+    SPAN_PUT,
+    SPAN_RESTORE,
+    SPAN_SAMPLE,
+    SPAN_STEP,
+    SPAN_SWAP_OUT,
+    Histogram,
+)
 
 
 @dataclass
@@ -204,6 +221,26 @@ class _StagedBatch:
     gen: int
     samplers: List[tuple]
     inputs: tuple
+
+
+def _plan_stats(plan) -> Dict[str, int]:
+    """A plan's counts, as span stats."""
+    reqs = [c.request for c in plan.prefill_chunks] + list(plan.decode_reqs)
+    online = sum(1 for r in reqs if r.is_online)
+    return {
+        "sequences": len(reqs),
+        "tokens": plan.shape.total_tokens,
+        "online": online,
+        "offline": len(reqs) - online,
+        "preempted": len(plan.preempted),
+    }
+
+
+def _ckpt_stats(chosen) -> Dict[str, Any]:
+    """Blocks and request ids of one checkpoint copy, as span stats (ids
+    space-separated: the profiler splits stats at commas)."""
+    ids = sorted({c[0] for c in chosen})
+    return {"blocks": len(chosen), "requests": " ".join(map(str, ids))}
 
 
 class RealEngine:
@@ -343,13 +380,18 @@ class RealEngine:
         # gap's clock started (a drain point on serial turns, the last
         # enqueue otherwise); ``_last_out`` is the final array enqueued —
         # if it is still not ready when the next batch is handed over, the
-        # device never idled and the sample records 0.  The counters are
-        # monotone (never reset); the list feeds bench percentiles.
+        # device never idled and the sample records 0.  A fixed-bucket
+        # histogram (count and sum monotone, never reset), so its memory
+        # stays bounded for the life of a server; the runtime publishes it.
         self._t_last_enqueue: Optional[float] = None
         self._last_out: Optional[Any] = None
-        self.host_gap_s: List[float] = []
-        self.host_gap_count = 0
-        self.host_gap_seconds = 0.0
+        self.host_gap = Histogram("host_gap_seconds", HOST_GAP_BOUNDS)
+        # KV blocks copied out by checkpoints, scattered back by resumes
+        # and duplicated by copy-on-write (monotone; published as
+        # ``kv_<kind>_blocks_total``)
+        self.kv_blocks = {"checkpoint": 0, "restore": 0, "cow": 0}
+        # tokens of the plan the last step executed (0: it ran none)
+        self.plan_tokens = 0
         # Calibration-drift instrumentation (DESIGN.md §15): cumulative
         # measured step wall time vs the installed latency model's
         # prediction for the same batch shapes.  The serial engine measures
@@ -360,7 +402,6 @@ class RealEngine:
         # publishes the ratio as ``calibration_drift``.
         self.measured_iter_seconds = 0.0
         self.predicted_iter_seconds = 0.0
-        self.measured_iters = 0
         if self.paged:
             # Shared physical pools + one scratch row (id num_device_blocks)
             # that absorbs writes from padded batch rows / padded table
@@ -825,6 +866,13 @@ class RealEngine:
 
     # ---------------------------------------------------------------- events
     def _process_events(self) -> None:
+        if not self.sched.events:
+            return
+        with TraceAnnotation(SPAN_EVENTS, events=len(self.sched.events)):
+            self._apply_events()
+        self.sched.events.clear()
+
+    def _apply_events(self) -> None:
         if self._ckpt_pending and any(
             kind == "resume" for kind, _r, _p in self.sched.events
         ):
@@ -839,11 +887,13 @@ class RealEngine:
                     # blocking swap-out: copy the un-checkpointed blocks now
                     # (checkpointed ones are already in the host store)
                     if self.paged and payload:
-                        stored = self._extract_blocks_paged(
-                            [dev for _idx, dev, _host in payload]
-                        )
-                        for (idx, _dev, _host), blk in zip(payload, stored):
-                            self.host.put(rid, idx, blk)
+                        with TraceAnnotation(SPAN_SWAP_OUT, request=rid,
+                                             blocks=len(payload)):
+                            stored = self._extract_blocks_paged(
+                                [dev for _idx, dev, _host in payload]
+                            )
+                            for (idx, _dev, _host), blk in zip(payload, stored):
+                                self.host.put(rid, idx, blk)
                     elif not self.paged:
                         cache = self.caches.get(rid)
                         for idx, _dev, _host in payload:
@@ -862,7 +912,10 @@ class RealEngine:
                 # divergence — drop them (the manager already released the
                 # host blocks) so a later resume can never restore stale KV.
                 if self.paged and payload:
-                    self._cow_blocks_paged(payload)
+                    self.kv_blocks["cow"] += len(payload)
+                    with TraceAnnotation(SPAN_COW, request=rid,
+                                         blocks=len(payload)):
+                        self._cow_blocks_paged(payload)
                 for idx, _src, _dst in payload:
                     self.host.pop(rid, idx)
             elif kind == "resume":
@@ -876,7 +929,10 @@ class RealEngine:
                             devs.append(sb.device_blocks[b])
                             blks.append(stored)
                     if devs:
-                        self._restore_blocks_paged(devs, blks)
+                        self.kv_blocks["restore"] += len(devs)
+                        with TraceAnnotation(SPAN_RESTORE, request=rid,
+                                             blocks=len(devs)):
+                            self._restore_blocks_paged(devs, blks)
                 else:
                     cache = self._fresh_cache(req)
                     for b in range(nrec):
@@ -884,7 +940,6 @@ class RealEngine:
                         if stored is not None:
                             cache = self._restore_block(cache, b, stored)
                     self.caches[rid] = cache
-        self.sched.events.clear()
 
     # --------------------------------------------------- fault injection (§16)
     def _arm_iteration_faults(self, plan) -> None:
@@ -974,15 +1029,33 @@ class RealEngine:
     # ------------------------------------------------------------------ step
     def step(self) -> bool:
         """One engine iteration. Returns False when no work remains."""
-        if self.pipeline:
-            return self._step_pipelined()
+        self.plan_tokens = 0
+        with TraceAnnotation(SPAN_STEP):
+            if self.pipeline:
+                return self._step_pipelined()
+            return self._step_serial()
+
+    def _plan(self, now: float):
+        """``plan_iteration`` under its span, with the plan's counts."""
+        with TraceAnnotation(SPAN_PLAN) as span:
+            plan = self.sched.plan_iteration(now)
+            span.set_metadata(**_plan_stats(plan))
+        return plan
+
+    def _commit(self, plan, aborted: bool, tokens) -> None:
+        with TraceAnnotation(SPAN_COMMIT, aborted=int(aborted),
+                             **_plan_stats(plan)):
+            self.sched.commit(plan, self._clock(), aborted=aborted,
+                              tokens=tokens)
+
+    def _step_serial(self) -> bool:
         now = self._clock()
         sched = self.sched
         if self.faults is not None:
             # pre-iteration cut for request-scoped fault rollback (§16)
             self._step_snap = sched.snapshot()
             self._step_snap_staged = False
-        plan = sched.plan_iteration(now)
+        plan = self._plan(now)
         self._process_events()
         if plan.empty:
             self._step_snap = None
@@ -990,6 +1063,7 @@ class RealEngine:
                 sched.online_q or sched.offline_q or sched.running or sched.preempted
             )
         self.steps += 1
+        self.plan_tokens = plan.shape.total_tokens
         t_iter0 = time.perf_counter()
         predicted_s = self.sched.model.iter_time(plan.shape)
         self._arm_iteration_faults(plan)
@@ -1031,13 +1105,12 @@ class RealEngine:
                     for i, r in enumerate(reqs):
                         tokens[r.request_id] = int(toks[i])
 
-        sched.commit(plan, self._clock(), aborted=aborted, tokens=tokens)
+        self._commit(plan, aborted, tokens)
         # the iteration is committed: token progress is now commit-owned
         # state the snapshot does not capture, so the rollback cut is gone
         self._step_snap = None
         self.measured_iter_seconds += time.perf_counter() - t_iter0
         self.predicted_iter_seconds += predicted_s
-        self.measured_iters += 1
         if not self.paged:
             for r in list(self.caches):
                 if not self.blocks.has_seq(r):
@@ -1064,6 +1137,11 @@ class RealEngine:
         chosen = self.ckpt.plan(io_budget_blocks=1 << 30)
         if not chosen:
             return
+        self.kv_blocks["checkpoint"] += len(chosen)
+        with TraceAnnotation(SPAN_CHECKPOINT, **_ckpt_stats(chosen)):
+            self._copy_checkpoint(chosen)
+
+    def _copy_checkpoint(self, chosen) -> None:
         if self.paged:
             if self.pipeline:
                 n = len(chosen)
@@ -1097,18 +1175,19 @@ class RealEngine:
         sequence freed since the gather was enqueued (it finished in the
         meantime) is skipped — its host entries were already dropped."""
         for chosen, staged in self._ckpt_pending:
-            staged = jax.device_get(staged)
-            for i, (seq_id, idx, _dev, _host) in enumerate(chosen):
-                if not self.blocks.has_seq(seq_id):
-                    continue
-                self.host.put(
-                    seq_id,
-                    idx,
-                    {
-                        pos: {"k": b["k"][:, i], "v": b["v"][:, i]}
-                        for pos, b in staged.items()
-                    },
-                )
+            with TraceAnnotation(SPAN_CHECKPOINT, **_ckpt_stats(chosen)):
+                staged = jax.device_get(staged)
+                for i, (seq_id, idx, _dev, _host) in enumerate(chosen):
+                    if not self.blocks.has_seq(seq_id):
+                        continue
+                    self.host.put(
+                        seq_id,
+                        idx,
+                        {
+                            pos: {"k": b["k"][:, i], "v": b["v"][:, i]}
+                            for pos, b in staged.items()
+                        },
+                    )
         self._ckpt_pending.clear()
 
     # ------------------------------------------------- fused ragged execution
@@ -1228,9 +1307,20 @@ class RealEngine:
                 # handed over: zero observable idle (§13)
                 gap = 0.0
             self._t_last_enqueue = None
-            self.host_gap_s.append(gap)
-            self.host_gap_count += 1
-            self.host_gap_seconds += gap
+            self.host_gap.observe(gap)
+        segs = self.dispatches["fused_segment"]
+        with TraceAnnotation(SPAN_DISPATCH) as span:
+            logits, aborted = self._enqueue_fused(
+                toks, tables, positions, meta, logit_idx, preemptible
+            )
+            span.set_metadata(
+                segments=self.dispatches["fused_segment"] - segs,
+                aborted=int(aborted),
+            )
+        return logits, aborted
+
+    def _enqueue_fused(self, toks, tables, positions, meta, logit_idx,
+                       preemptible: bool):
         x = tf.embed(self.cfg, self.params, toks[None])
         if self.pipeline:
             # per-segment split pools (§13): each segment program donates
@@ -1297,6 +1387,36 @@ class RealEngine:
         *repeating* a real pair, which is idempotent under ``.at[].set``
         (the padded slot at ``t_pad - 1`` may be a real token when the
         batch exactly fills its bucket, so padding with it is unsafe)."""
+        with TraceAnnotation(SPAN_BUILD) as span:
+            items, samplers, inj = self._fused_items(plan)
+            arrays = self._build_ragged(items)
+            span.set_metadata(
+                sequences=len(items),
+                tokens=sum(it[0] for it in items),
+                bucket="%d,%d,%d" % (len(arrays["tokens"]),
+                                     len(arrays["kv_lens"]),
+                                     arrays["qpad"].shape[1]),
+            )
+        with TraceAnnotation(SPAN_PUT) as span:
+            inputs = self._fused_inputs(arrays)
+            if inj:
+                toks_d, tables, positions, meta, li = inputs
+                pad = pow2_bucket(len(inj))
+                inj = inj + [inj[-1]] * (pad - len(inj))
+                toks_d = self._inject_jit(
+                    toks_d,
+                    self._put(np.asarray([i for i, _ in inj], np.int32)),
+                    self._fetches[-1].arr,
+                    self._put(np.asarray([r for _, r in inj], np.int32)),
+                )
+                inputs = (toks_d, tables, positions, meta, li)
+            span.set_metadata(arrays=len(arrays) + (2 if inj else 0))
+        return samplers, inputs
+
+    def _fused_items(self, plan) -> Tuple[List[tuple], List[tuple], List[tuple]]:
+        """The plan's ``_build_ragged`` items, the ``(sequence row,
+        request)`` pairs to sample, and the ``(flat token slot, row in
+        pending samples)`` pairs to inject."""
         pend: Dict[int, int] = {}
         if self._fetches:
             latest = self._fetches[-1]
@@ -1322,26 +1442,14 @@ class RealEngine:
             if row is None:
                 tok = self._tokens_of(r)[-1:]
             else:
-                tok = np.zeros((1,), np.int32)  # injected on device below
+                tok = np.zeros((1,), np.int32)  # injected on device
                 inj.append((start, row))
             items.append(
                 (1, r.total_len - 1, tok, self._block_table(r.request_id))
             )
             samplers.append((len(items) - 1, r))
             start += 1
-        inputs = self._fused_inputs(self._build_ragged(items))
-        if inj:
-            toks_d, tables, positions, meta, li = inputs
-            pad = pow2_bucket(len(inj))
-            inj = inj + [inj[-1]] * (pad - len(inj))
-            toks_d = self._inject_jit(
-                toks_d,
-                self._put(np.asarray([i for i, _ in inj], np.int32)),
-                self._fetches[-1].arr,
-                self._put(np.asarray([r for _, r in inj], np.int32)),
-            )
-            inputs = (toks_d, tables, positions, meta, li)
-        return samplers, inputs
+        return items, samplers, inj
 
     def _run_fused(
         self, plan, preemptible: bool, tokens: Dict[int, int]
@@ -1359,11 +1467,12 @@ class RealEngine:
         if aborted:
             return True
         if samplers:
-            rows = jnp.asarray([i for i, _ in samplers])
-            self._key, sk = jax.random.split(self._key)
-            toks = np.asarray(sample(logits[rows], self.sampling, sk))
-            for (_, r), t in zip(samplers, toks):
-                tokens[r.request_id] = int(t)
+            with TraceAnnotation(SPAN_SAMPLE, rows=len(samplers)):
+                rows = jnp.asarray([i for i, _ in samplers])
+                self._key, sk = jax.random.split(self._key)
+                toks = np.asarray(sample(logits[rows], self.sampling, sk))
+                for (_, r), t in zip(samplers, toks):
+                    tokens[r.request_id] = int(t)
             self._last_out = None  # the readback above drained the device
         else:
             self._last_out = logits  # queue may still be busy
@@ -1414,7 +1523,7 @@ class RealEngine:
                 # pre-iteration cut for request-scoped fault rollback (§16)
                 self._step_snap = sched.snapshot()
                 self._step_snap_staged = False
-            plan = sched.plan_iteration(now)
+            plan = self._plan(now)
             self._process_events()
             if plan.empty:
                 self._step_snap = None
@@ -1438,6 +1547,7 @@ class RealEngine:
             sched.t_sched = now
             self._process_events()
         self.steps += 1
+        self.plan_tokens = plan.shape.total_tokens
         t_iter0 = time.perf_counter()
         predicted_s = self.sched.model.iter_time(plan.shape)
         self._arm_iteration_faults(plan)
@@ -1451,24 +1561,24 @@ class RealEngine:
             self.flag.clear()
         logits, aborted = self._dispatch_fused(*inputs, preemptible=preemptible)
         if aborted:
-            sched.commit(plan, self._clock(), aborted=True, tokens={})
+            self._commit(plan, True, {})
             self._step_snap = None
             self.measured_iter_seconds += time.perf_counter() - t_iter0
             self.predicted_iter_seconds += predicted_s
-            self.measured_iters += 1
             return True
 
         if samplers:
-            rows = [i for i, _ in samplers]
-            pad = pow2_bucket(len(rows))
-            rows_arr = self._put(
-                np.asarray(rows + [rows[-1]] * (pad - len(rows)), np.int32)
-            )
-            self._key, sk = jax.random.split(self._key)
-            sampled = self._sample_jit(logits, rows_arr, sk)
-            self._fetches.append(
-                _PendingFetch(sampled, [r for _, r in samplers])
-            )
+            with TraceAnnotation(SPAN_SAMPLE, rows=len(samplers)):
+                rows = [i for i, _ in samplers]
+                pad = pow2_bucket(len(rows))
+                rows_arr = self._put(
+                    np.asarray(rows + [rows[-1]] * (pad - len(rows)), np.int32)
+                )
+                self._key, sk = jax.random.split(self._key)
+                sampled = self._sample_jit(logits, rows_arr, sk)
+                self._fetches.append(
+                    _PendingFetch(sampled, [r for _, r in samplers])
+                )
             self._last_out = sampled
         else:
             self._last_out = logits
@@ -1477,11 +1587,10 @@ class RealEngine:
         # this iteration can no longer abort; tokens=None counts generated
         # tokens without values (record_token(None)), the pending fetch
         # backfills output_tokens before anything on host reads them
-        sched.commit(plan, self._clock(), aborted=False, tokens=None)
+        self._commit(plan, False, None)
         self._step_snap = None
         self.measured_iter_seconds += time.perf_counter() - t_iter0
         self.predicted_iter_seconds += predicted_s
-        self.measured_iters += 1
 
         # All remaining post-work runs BEFORE the speculation snapshot so a
         # rollback only ever reverts the speculative plan's own mutations.
@@ -1504,7 +1613,7 @@ class RealEngine:
         the staged batch (input transfers, the token injection) simply
         goes unread on discard."""
         snap = self.sched.snapshot()
-        plan = self.sched.plan_iteration(self._clock())
+        plan = self._plan(self._clock())
         if plan.empty:
             self.sched.restore(snap)
             return
@@ -1517,8 +1626,12 @@ class RealEngine:
         the steady-state step keeps exactly one (the iteration still on
         device), which speculation reads via device-side injection."""
         keep = 1 if keep_latest else 0
-        while len(self._fetches) > keep:
-            self._fetches.popleft().resolve()
+        if len(self._fetches) > keep:
+            rows = sum(len(self._fetches[i].reqs)
+                       for i in range(len(self._fetches) - keep))
+            with TraceAnnotation(SPAN_SAMPLE, rows=rows):
+                while len(self._fetches) > keep:
+                    self._fetches.popleft().resolve()
         self._drop_retired()
 
     def _drop_retired(self) -> None:

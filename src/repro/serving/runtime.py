@@ -79,6 +79,8 @@ import warnings
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 from repro.core.faults import (
     EngineDead,
     RequestFailed,
@@ -89,7 +91,15 @@ from repro.core.request import Phase, Request
 from repro.core.scheduler import AdmissionError
 from repro.core.slo import ServiceMetrics, SLOTracker, summarize
 from repro.serving.api import EngineStalled, QueueFull, QueueTimeout, TokenChannel
-from repro.serving.metrics import MetricsRegistry
+from repro.serving.metrics import (
+    SPAN_BACKOFF,
+    SPAN_DRAIN,
+    SPAN_IDLE,
+    SPAN_ITERATION,
+    SPAN_PUBLISH,
+    SPAN_PUMP,
+    MetricsRegistry,
+)
 
 
 class ManualClock:
@@ -206,6 +216,7 @@ class CoServingRuntime:
         # mutated on the engine thread only
         self._streams: Dict[int, list] = {}
         self._slo_tracker = SLOTracker(engine.sched.slo)
+        self._queue_waited: set = set()  # ids whose queue wait is observed
         self._prompt_tokens_delivered = 0
         # ---- failure domains / health (DESIGN.md §16) -------------------
         self._health = RuntimeHealth.HEALTHY
@@ -217,6 +228,9 @@ class CoServingRuntime:
         self.failed: List[Request] = []  # request-scoped casualties
         engine.set_clock(self.now)
         engine.arrival_poll = self._drain_arrivals
+        gap = getattr(engine, "host_gap", None)
+        if gap is not None:
+            self.registry.adopt(gap)
 
     @property
     def sched(self):
@@ -479,6 +493,11 @@ class CoServingRuntime:
         """Deliver due arrivals into the scheduler.  Engine thread only:
         runs at loop-top each iteration and at every safepoint between
         K-layer segment dispatches (``engine.arrival_poll``)."""
+        with TraceAnnotation(SPAN_DRAIN) as span:
+            span.set_metadata(arrivals=self._deliver_due())
+
+    def _deliver_due(self) -> int:
+        """``_drain_arrivals``' work; returns the arrivals delivered."""
         now = self.now()
         due: List[Request] = []
         while (
@@ -515,6 +534,7 @@ class CoServingRuntime:
             depths = self.engine.sched.queue_depths()
             with self._lock:
                 self._sched_depths = depths
+        return len(due)
 
     def _flush_engine(self) -> None:
         """Drain the engine's asynchronous pipeline artifacts (pending
@@ -581,6 +601,13 @@ class CoServingRuntime:
             else:
                 gen_off += r.num_generated
                 proc_off += proc
+            if (r.first_scheduled_time is not None
+                    and r.request_id not in self._queue_waited):
+                self._queue_waited.add(r.request_id)
+                reg.histogram(
+                    "queue_wait_seconds_"
+                    + ("online" if r.is_online else "offline")
+                ).observe(r.first_scheduled_time - r.arrival_time)
         reg.counter("tokens_generated_total_online").set_to(gen_on)
         reg.counter("tokens_generated_total_offline").set_to(gen_off)
         reg.counter("tokens_processed_total_online").set_to(proc_on)
@@ -615,13 +642,10 @@ class CoServingRuntime:
         reg.counter("iter_predicted_seconds_total").set_to(predicted)
         if predicted > 0.0:
             reg.gauge("calibration_drift").set(measured / predicted)
+        # KV copies; the host-gap histogram was adopted at construction
+        for kind, n in getattr(eng, "kv_blocks", {}).items():
+            reg.counter(f"kv_{kind}_blocks_total").set_to(n)
         # async pipeline (§13)
-        reg.counter("host_gap_seconds_total").set_to(
-            getattr(eng, "host_gap_seconds", 0.0)
-        )
-        reg.counter("host_gap_count_total").set_to(
-            getattr(eng, "host_gap_count", 0)
-        )
         reg.counter("pipeline_discards_total").set_to(
             getattr(eng, "pipeline_discards", 0)
         )
@@ -668,17 +692,29 @@ class CoServingRuntime:
             return False
 
     def _step_guarded(self) -> bool:
-        self._drain_arrivals()
-        before = self.engine.steps
-        alive = self.engine.step()
-        self._note_degradation()
-        self._observe_aborts()
-        self._pump_streams()
-        self._publish_metrics()
-        if alive and self.engine.steps == before:
-            # work exists but nothing was schedulable (e.g. memory wedged
-            # behind a pending resume): back off instead of spinning
-            self._sleep(self.idle_backoff_s)
+        """One turn of the loop under its root span: ``step_num`` counts
+        engine steps, ``t_host`` is this runtime's clock at the start (one
+        offset maps the runtime's timestamps onto the trace's clock), and
+        ``tokens`` the planned tokens (0: nothing ran)."""
+        eng = self.engine
+        before = eng.steps
+        with StepTraceAnnotation(
+            SPAN_ITERATION, step_num=before, t_host=self.now()
+        ) as span:
+            self._drain_arrivals()
+            alive = eng.step()
+            span.set_metadata(tokens=eng.plan_tokens)
+            self._note_degradation()
+            self._observe_aborts()
+            with TraceAnnotation(SPAN_PUMP):
+                self._pump_streams()
+            with TraceAnnotation(SPAN_PUBLISH):
+                self._publish_metrics()
+            if alive and eng.steps == before:
+                # work exists but nothing was schedulable (e.g. memory
+                # wedged behind a pending resume): back off, not spin
+                with TraceAnnotation(SPAN_BACKOFF):
+                    self._sleep(self.idle_backoff_s)
         return alive
 
     def _recover_request_fault(self, rf: RequestFailed) -> None:
@@ -776,7 +812,8 @@ class CoServingRuntime:
                         # idle until the next trace arrival
                         gap = self._trace[self._trace_pos].arrival_time - self.now()
                         if gap > 0:
-                            self._sleep(gap)
+                            with TraceAnnotation(SPAN_IDLE):
+                                self._sleep(gap)
                         continue
                     break
             else:
@@ -822,7 +859,8 @@ class CoServingRuntime:
                     # nothing to do: wait for arrivals without burning CPU
                     # (through the injected sleep — a ManualClock runtime
                     # must not busy-wait real time)
-                    self._sleep(self.idle_backoff_s)
+                    with TraceAnnotation(SPAN_IDLE):
+                        self._sleep(self.idle_backoff_s)
 
         self._thread = threading.Thread(
             target=loop, name="coserve-engine", daemon=True

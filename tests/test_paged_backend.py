@@ -401,8 +401,8 @@ def test_pipelined_retrace_regression_guard_mixed_onoff_drain():
         eng.submit(mkreq(Priority.OFFLINE, p, g, s))
     for _ in range(4):
         eng.step()
-    gap_count_mid = eng.host_gap_count
-    gap_seconds_mid = eng.host_gap_seconds
+    gap_count_mid = eng.host_gap.count
+    gap_seconds_mid = eng.host_gap.sum
     for s in range(3):
         eng.on_online_arrival(mkreq(Priority.ONLINE, 60, 8, 100 + s))
     eng.run()
@@ -423,13 +423,17 @@ def test_pipelined_retrace_regression_guard_mixed_onoff_drain():
         f"pipeline program retraces changed: {eng.pipeline_trace_count} "
         "(was 8); did sample-row / injection bucketing break?"
     )
-    # host-gap instrumentation: counters are monotone (never reset) and
-    # stay consistent with the per-iteration sample list
-    assert eng.host_gap_count >= gap_count_mid
-    assert eng.host_gap_seconds >= gap_seconds_mid
-    assert eng.host_gap_count == len(eng.host_gap_s)
-    assert eng.host_gap_seconds == pytest.approx(sum(eng.host_gap_s))
-    assert all(g >= 0.0 for g in eng.host_gap_s)
+    # host-gap instrumentation: count and sum are monotone (never reset)
+    # and stay consistent with the histogram's buckets: every sample is
+    # >= 0 and lies in its bucket, so the sum is bracketed by the bounds
+    gap = eng.host_gap
+    assert gap.count >= gap_count_mid
+    assert gap.sum >= gap_seconds_mid
+    assert gap.count == sum(gap.counts)
+    lo = (0.0,) + gap.bounds
+    hi = gap.bounds + (float("inf"),)
+    assert sum(c * b for c, b in zip(gap.counts, lo)) <= gap.sum + 1e-12
+    assert gap.sum <= sum(c * b for c, b in zip(gap.counts, hi) if c)
 
 
 def test_prefix_retrace_regression_guard_shared_drain():
