@@ -362,14 +362,16 @@ def mesh_tokens(tp: int, *, reduced=False, seed=0, max_model_len=512,
 
 
 def pool_shards_ok(eng, tp: int) -> bool:
-    """Every pool leaf has one shard per chip, each with Hkv/tp heads."""
+    """Every pool leaf has one shard per chip, each with the lanes of
+    Hkv/tp whole heads."""
     import jax
 
+    lanes = eng.cfg.num_kv_heads // tp * eng.cfg.resolved_head_dim
     for leaf in jax.tree.leaves(eng.pools):
         shards = leaf.addressable_shards
         if len({s.device for s in shards}) != tp:
             return False
-        if any(s.data.shape[2] != eng.cfg.num_kv_heads // tp for s in shards):
+        if any(s.data.shape[-1] != lanes for s in shards):
             return False
     return True
 

@@ -272,21 +272,23 @@ def cache_shardings(cache_spec: PyTree, mesh: Mesh) -> PyTree:
 
 
 def pool_pspec(shape: Sequence[int], mesh: Mesh) -> P:
-    """Paged-pool leaves are (num_periods, num_blocks, Hkv, block_size, D).
+    """Paged-pool leaves are (num_periods, num_blocks, block_size, Hkv·D),
+    the lanes head-major.
 
-    Tensor-parallel serving shards the KV-HEAD axis over ``model``: every
-    chip owns Hkv/tp heads of *every* physical block, so the block table
+    Tensor-parallel serving shards the lane axis over ``model``: every chip
+    owns Hkv/tp whole heads of *every* physical block, so the block table
     stays replicated and identical on all chips and block allocation /
     preemption / checkpoint bookkeeping is mesh-oblivious.  The heads must
-    divide the axis (``RealEngine`` refuses a mesh whose axis does not) —
-    never a silent replication, and never a head_dim split: D is the
-    contraction dim of the attention dots, and a sharded contraction turns
-    into partial-sum all-reduces whose float summation order breaks the
-    bitwise token identity the differential harness asserts (DESIGN.md §11).
+    divide the axis (``RealEngine`` refuses a mesh whose axis does not), so
+    a shard's lanes are whole heads — never a silent replication, and never
+    a head_dim split: D is the contraction dim of the attention dots, and a
+    sharded contraction turns into partial-sum all-reduces whose float
+    summation order breaks the bitwise token identity the differential
+    harness asserts (DESIGN.md §11).
     """
     spec: list = [None] * len(shape)
     if mesh_axis_size(mesh, "model") > 1:
-        spec[2] = "model"
+        spec[-1] = "model"
     return P(*spec)
 
 

@@ -28,33 +28,32 @@ def _gather_kernel(ids_ref, pool_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def checkpoint_gather(
-    pool: jnp.ndarray,  # (N, Hkv, page, D)
+    pool: jnp.ndarray,  # (N, page, Hkv*D) — or any (N, ...) page layout
     block_ids: jnp.ndarray,  # (K,) int32 — device pages to checkpoint
     *,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Returns the packed staging buffer (K, Hkv, page, D)."""
-    n, hkv, page, d = pool.shape
+    """Returns the packed staging buffer (K, ...) of whole pages."""
+    page = pool.shape[1:]
+    rest = (0,) * len(page)
     k = block_ids.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(k,),
-        in_specs=[
-            pl.BlockSpec((1, hkv, page, d), lambda i, ids: (ids[i], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, hkv, page, d), lambda i, ids: (i, 0, 0, 0)),
+        in_specs=[pl.BlockSpec((1, *page), lambda i, ids: (ids[i], *rest))],
+        out_specs=pl.BlockSpec((1, *page), lambda i, ids: (i, *rest)),
     )
     return pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((k, hkv, page, d), pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((k, *page), pool.dtype),
         interpret=interpret,
     )(block_ids.astype(jnp.int32), pool)
 
 
 def checkpoint_scatter(
-    pool: jnp.ndarray,  # (N, Hkv, page, D)
-    staging: jnp.ndarray,  # (K, Hkv, page, D) — swapped-in pages
+    pool: jnp.ndarray,  # (N, page, Hkv*D)
+    staging: jnp.ndarray,  # (K, page, Hkv*D) — swapped-in pages
     block_ids: jnp.ndarray,  # (K,) destination device pages
 ) -> jnp.ndarray:
     """Swap-in: scatter staged pages back into the pool (prefetch path).

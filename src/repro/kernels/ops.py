@@ -69,8 +69,9 @@ def flash_attention(q, k, v, *, causal=True, sliding_window=0, q_offset=0,
 
 def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, *,
                     logit_softcap=0.0, mesh=None):
-    """``mesh``: tensor-parallel serving mesh (DESIGN.md §11).  The Pallas
-    path shard_maps the kernel over KV heads; the jnp reference needs no
+    """Decode attention over one layer's pool (N, page, Hkv·D).  ``mesh``:
+    tensor-parallel serving mesh (DESIGN.md §11).  The Pallas path
+    shard_maps the kernel over KV heads; the jnp reference needs no
     explicit handling — its operands arrive sharding-constrained and GSPMD
     partitions the oracle einsums over the head axis."""
     be = kernel_backend()
@@ -79,34 +80,39 @@ def paged_attention(q, k_pool, v_pool, block_tables, seq_lens, *,
             q, k_pool, v_pool, block_tables, seq_lens,
             logit_softcap=logit_softcap,
         )
-    # the Qmax = 1 case of the ragged kernel, the query at seq_len - 1
+    # the Qmax = 1 case of the ragged kernel over a one-layer stack, the
+    # query at seq_len - 1
     return ragged_paged_attention(
-        q[:, None], k_pool, v_pool, block_tables, (seq_lens - 1)[:, None],
-        seq_lens, logit_softcap=logit_softcap, mesh=mesh,
+        q[:, None], k_pool[None], v_pool[None], block_tables,
+        (seq_lens - 1)[:, None], seq_lens, layer=jnp.int32(0),
+        logit_softcap=logit_softcap, mesh=mesh,
     )[:, 0]
 
 
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, q_positions,
-                           kv_lens, *, logit_softcap=0.0, mesh=None):
-    """Fused mixed-batch attention over the paged pool (DESIGN.md §12):
-    one dispatch serves every sequence of an iteration, prefill chunks and
-    decodes alike.  Same backend dispatch contract as ``paged_attention``:
-    Pallas (shard_mapped over KV heads on a mesh) on TPU, the
-    ``cache_ops`` jnp oracle on CPU — where GSPMD partitions the oracle
-    einsums over the already-constrained head axis."""
+                           kv_lens, *, layer, logit_softcap=0.0, mesh=None):
+    """Fused mixed-batch attention over layer ``layer`` of the stacked pool
+    (L, N, page, Hkv·D) (DESIGN.md §12): one dispatch serves every
+    sequence of an iteration, prefill chunks and decodes alike.  Same
+    backend dispatch contract as ``paged_attention``: Pallas (shard_mapped
+    over KV heads on a mesh) on TPU, its index map reading the layer out of
+    the stack; the ``cache_ops`` jnp oracle on that layer's slice on CPU —
+    where GSPMD partitions the oracle einsums over the already-constrained
+    head axis."""
     be = kernel_backend()
     if be == "ref":
         return ref.ragged_paged_attention_ref(
-            q, k_pool, v_pool, block_tables, q_positions, kv_lens,
-            logit_softcap=logit_softcap,
+            q, k_pool[layer], v_pool[layer], block_tables, q_positions,
+            kv_lens, logit_softcap=logit_softcap,
         )
     if mesh is not None:
         return _ragged_shmap(
             q, k_pool, v_pool, block_tables, q_positions, kv_lens, mesh,
-            logit_softcap=logit_softcap, interpret=(be == "interpret"),
+            layer=layer, logit_softcap=logit_softcap,
+            interpret=(be == "interpret"),
         )
     return _ragged_pallas(
-        q, k_pool, v_pool, block_tables, q_positions, kv_lens,
+        q, k_pool, v_pool, block_tables, q_positions, kv_lens, layer=layer,
         logit_softcap=logit_softcap, interpret=(be == "interpret"),
     )
 

@@ -1,8 +1,13 @@
 """JAX ops over the *paged* physical KV layout.
 
-Physical pool per layer: ``k_pool, v_pool: (num_blocks, Hkv, block_size, D)``
-— KV heads outside the block, so one head's page is a ``(block_size, D)``
-tile the Pallas kernel can load as a block.  Sequences address it through
+Physical pool per layer: ``k_pool, v_pool: (num_blocks, block_size, Hkv·D)``
+— one page is a lane-dense ``(block_size, Hkv·D)`` tile holding every KV
+head of its tokens, head-major along the lanes: the chip's own row-major
+tiled layout, which the Pallas kernel DMAs as one block and no program
+relays out (DESIGN.md §12).  The serving pools stack these per layer,
+``(layers, num_blocks, block_size, Hkv·D)``; the fused program writes a
+layer of the stack through its rows in the flattened stack and the kernel
+reads it by index.  Sequences address it through
 ``block_tables: (B, max_blocks_per_seq) int32`` (-1 padded) +
 ``seq_lens: (B,)``.
 
@@ -29,13 +34,14 @@ def append_paged(
 
     Negative (padding) table entries drop the write instead of aliasing a
     real block — padded batch rows are harmless by construction."""
-    bs = k_pool.shape[2]
+    b = k_new.shape[0]
+    bs = k_pool.shape[1]
     block_idx = seq_lens // bs
     offset = seq_lens % bs
     rows = jnp.take_along_axis(block_tables, block_idx[:, None], axis=1)[:, 0]
     rows = jnp.where(rows >= 0, rows, k_pool.shape[0])  # pad -> OOB -> drop
-    k_pool = k_pool.at[rows, :, offset].set(k_new, mode="drop")
-    v_pool = v_pool.at[rows, :, offset].set(v_new, mode="drop")
+    k_pool = k_pool.at[rows, offset].set(k_new.reshape(b, -1), mode="drop")
+    v_pool = v_pool.at[rows, offset].set(v_new.reshape(b, -1), mode="drop")
     return k_pool, v_pool
 
 
@@ -54,20 +60,21 @@ def write_paged_chunk(
     landing on padding (negative table entries, or beyond the table width)
     drop the write rather than aliasing a real block.
     """
-    bs = k_pool.shape[2]
+    b, l = positions.shape
+    bs = k_pool.shape[1]
     m = block_tables.shape[1]
     in_table = positions // bs < m  # (B, L)
     block_idx = jnp.clip(positions // bs, 0, m - 1)
     offsets = positions % bs
     rows = jnp.take_along_axis(block_tables, block_idx, axis=1)  # (B, L)
     rows = jnp.where((rows >= 0) & in_table, rows, k_pool.shape[0])  # drop
-    k_pool = k_pool.at[rows, :, offsets].set(k_new, mode="drop")
-    v_pool = v_pool.at[rows, :, offsets].set(v_new, mode="drop")
+    k_pool = k_pool.at[rows, offsets].set(k_new.reshape(b, l, -1), mode="drop")
+    v_pool = v_pool.at[rows, offsets].set(v_new.reshape(b, l, -1), mode="drop")
     return k_pool, v_pool
 
 
 def write_ragged(
-    k_pool: jnp.ndarray,
+    k_pool: jnp.ndarray,  # (num_blocks, bs, Hkv·D)
     v_pool: jnp.ndarray,
     k_new: jnp.ndarray,  # (T, Hkv, D) — flattened ragged token batch
     v_new: jnp.ndarray,
@@ -79,18 +86,21 @@ def write_ragged(
     The engine resolves each token's (block row, slot) on the host when it
     builds the ragged batch — the device sees a flat destination list, so
     prefill-chunk and decode tokens of a fused iteration land in ONE
-    scatter with no per-sequence table lookup.  Padded tokens carry the
-    scratch row; negative rows (not produced by the engine, but tolerated
-    for symmetry with ``write_paged_chunk``) drop the write.
+    scatter with no per-sequence table lookup.  The fused program hands it
+    its whole stacked pool seen as one pool of ``layers · num_blocks`` rows
+    (a free reshape), so only the T written rows move.  Padded tokens
+    carry the scratch row; negative rows (not produced by the engine, but
+    tolerated for symmetry with ``write_paged_chunk``) drop the write.
     """
+    t = k_new.shape[0]
     rows = jnp.where(dst_rows >= 0, dst_rows, k_pool.shape[0])
-    k_pool = k_pool.at[rows, :, dst_offsets].set(k_new, mode="drop")
-    v_pool = v_pool.at[rows, :, dst_offsets].set(v_new, mode="drop")
+    k_pool = k_pool.at[rows, dst_offsets].set(k_new.reshape(t, -1), mode="drop")
+    v_pool = v_pool.at[rows, dst_offsets].set(v_new.reshape(t, -1), mode="drop")
     return k_pool, v_pool
 
 
 def extract_block(pool: jnp.ndarray, block_id) -> jnp.ndarray:
-    """O(block) copy out of the pool by *physical* id: (Hkv, bs, D).
+    """O(block) copy out of the pool by *physical* id: (bs, Hkv·D).
 
     This (with ``write_block``) is the incremental-checkpoint unit — a
     preempt/resume moves whole physical blocks, never per-request pytrees.
@@ -104,7 +114,7 @@ def write_block(pool: jnp.ndarray, block_id, data: jnp.ndarray) -> jnp.ndarray:
 
 
 def copy_blocks(
-    pool: jnp.ndarray,  # (num_blocks, Hkv, bs, D)
+    pool: jnp.ndarray,  # (num_blocks, bs, Hkv·D)
     src_ids: jnp.ndarray,  # (N,) physical source blocks
     dst_ids: jnp.ndarray,  # (N,) physical destination blocks
 ) -> jnp.ndarray:
@@ -122,26 +132,25 @@ def copy_blocks(
 
 
 def gather_paged(
-    pool: jnp.ndarray,  # (num_blocks, Hkv, bs, D)
+    pool: jnp.ndarray,  # (num_blocks, bs, Hkv·D)
     block_tables: jnp.ndarray,  # (B, M)
     max_ctx: int,
+    head_dim: int,
 ) -> jnp.ndarray:
     """Gather per-sequence contiguous KV (B, max_ctx, Hkv, D)."""
-    _, hkv, bs, d = pool.shape
+    _, bs, width = pool.shape
     m = max_ctx // bs
     tables = block_tables[:, :m]  # (B, m)
     safe = jnp.maximum(tables, 0)
-    gathered = pool[safe]  # (B, m, Hkv, bs, D)
-    gathered = jnp.where(
-        (tables >= 0)[:, :, None, None, None], gathered, 0
-    )
+    gathered = pool[safe]  # (B, m, bs, Hkv·D)
+    gathered = jnp.where((tables >= 0)[:, :, None, None], gathered, 0)
     b = tables.shape[0]
-    return gathered.transpose(0, 1, 3, 2, 4).reshape(b, m * bs, hkv, d)
+    return gathered.reshape(b, m * bs, width // head_dim, head_dim)
 
 
 def paged_attention_ref(
     q: jnp.ndarray,  # (B, H, D) — single decode token per sequence
-    k_pool: jnp.ndarray,  # (num_blocks, Hkv, bs, D)
+    k_pool: jnp.ndarray,  # (num_blocks, bs, Hkv·D) — one layer's pool
     v_pool: jnp.ndarray,
     block_tables: jnp.ndarray,  # (B, M)
     seq_lens: jnp.ndarray,  # (B,) tokens valid in the cache (incl. current)
@@ -149,11 +158,11 @@ def paged_attention_ref(
 ) -> jnp.ndarray:
     """Oracle decode attention over the paged pool. Returns (B, H, D)."""
     b, h, d = q.shape
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[1]
     m = block_tables.shape[1]
     max_ctx = m * bs
-    k = gather_paged(k_pool, block_tables, max_ctx)  # (B, T, Hkv, D)
-    v = gather_paged(v_pool, block_tables, max_ctx)
+    k = gather_paged(k_pool, block_tables, max_ctx, d)  # (B, T, Hkv, D)
+    v = gather_paged(v_pool, block_tables, max_ctx, d)
     hkv = k.shape[2]
     g = h // hkv
     qg = q.reshape(b, hkv, g, d).astype(jnp.float32)
@@ -169,7 +178,7 @@ def paged_attention_ref(
 
 def ragged_paged_attention_ref(
     q: jnp.ndarray,  # (S, Qmax, H, D) — per-sequence padded query tokens
-    k_pool: jnp.ndarray,  # (num_blocks, Hkv, bs, D)
+    k_pool: jnp.ndarray,  # (num_blocks, bs, Hkv·D) — one layer's pool
     v_pool: jnp.ndarray,
     block_tables: jnp.ndarray,  # (S, M)
     q_positions: jnp.ndarray,  # (S, Qmax) absolute position of each query
@@ -190,11 +199,11 @@ def ragged_paged_attention_ref(
     ``q_len = 1``.  Returns (S, Qmax, H, D).
     """
     s, tq, h, d = q.shape
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[1]
     m = block_tables.shape[1]
     max_ctx = m * bs
-    k = gather_paged(k_pool, block_tables, max_ctx)  # (S, T, Hkv, D)
-    v = gather_paged(v_pool, block_tables, max_ctx)
+    k = gather_paged(k_pool, block_tables, max_ctx, d)  # (S, T, Hkv, D)
+    v = gather_paged(v_pool, block_tables, max_ctx, d)
     hkv = k.shape[2]
     g = h // hkv
     qg = q.reshape(s, tq, hkv, g, d)
@@ -214,10 +223,10 @@ def ragged_paged_attention_ref(
 
 
 def checkpoint_gather_ref(
-    pool: jnp.ndarray,  # (num_blocks, Hkv, bs, D)
+    pool: jnp.ndarray,  # (num_blocks, bs, Hkv·D)
     block_ids: jnp.ndarray,  # (N,) device blocks to checkpoint
 ) -> jnp.ndarray:
     """Oracle for the incremental-checkpoint delta gather: pack the selected
-    blocks into a dense staging buffer (N, Hkv, bs, D) for one contiguous
+    blocks into a dense staging buffer (N, bs, Hkv·D) for one contiguous
     device→host DMA."""
     return pool[block_ids]

@@ -394,19 +394,23 @@ def cached_attention(
 # ---------------------------------------------------------------------------
 # Paged attention (shared block pool, block-table addressed)
 #
-# The paged cache for one layer is {"k", "v"}: (num_blocks, Hkv, bs, D) —
-# a slice of the engine-owned shared pool.  Sequences address it through
-# ``block_tables`` (B, M); slot for absolute position p is
-# (table[p // bs], p % bs).  Keys are stored roped, exactly like the
-# contiguous cache, so preempt/resume restores are bitwise exact.
+# The paged cache for one layer is {"k", "v"}: (num_blocks, bs, Hkv·D) —
+# each page a lane-dense tile of every KV head, head-major along the lanes
+# (``kvcache.cache_ops``).  The split oracle paths below take one layer's
+# slice of the engine-owned shared pool; the fused path takes the stacked
+# pool and a layer index.  Sequences address it through ``block_tables``
+# (B, M); slot for absolute position p is (table[p // bs], p % bs).  Keys
+# are stored roped, exactly like the contiguous cache, so preempt/resume
+# restores are bitwise exact.
 #
 # Padding: negative table entries are read as zeros on the gather path and
 # *drop* writes on the scatter path; the engine additionally points padded
 # batch rows at a dedicated scratch block so their shapes stay uniform.
 #
 # Tensor-parallel serving (DESIGN.md §11): when a ``mesh`` is passed, the
-# pool and the q/k/v head axes are constrained over the mesh's ``model``
-# axis, so GSPMD computes attention head-parallel; the attention output is
+# pool's lane axis (whole heads, since the model axis divides Hkv) and the
+# q/k/v head axes are constrained over the mesh's ``model`` axis, so GSPMD
+# computes attention head-parallel; the attention output is
 # gathered (an exact, arithmetic-free collective) before the output
 # projection so no contraction ever runs over a sharded dim — sharded
 # serving therefore emits bitwise-identical tokens.
@@ -424,7 +428,8 @@ def _kv_shard_mesh(pool: Dict[str, jnp.ndarray], mesh):
 
 
 def shard_paged_heads(x: jnp.ndarray, mesh, head_axis: int) -> jnp.ndarray:
-    """Constrain the (kv-)head axis of ``x`` over the mesh's ``model`` axis.
+    """Constrain the (kv-)head axis of ``x`` over the mesh's ``model`` axis
+    — for a pool, its head-major lane axis, split into whole heads.
 
     No-op when ``mesh`` is None (see ``distributed.sharding.pool_pspec``
     for why head_dim is never split)."""
@@ -479,12 +484,13 @@ def paged_prefill_attention(
     k_pool, v_pool = write_paged_chunk(
         pool["k"], pool["v"], k, v, block_tables, positions
     )
-    k_pool = shard_paged_heads(k_pool, mesh, 1)
-    v_pool = shard_paged_heads(v_pool, mesh, 1)
-    bs = k_pool.shape[2]
+    k_pool = shard_paged_heads(k_pool, mesh, -1)
+    v_pool = shard_paged_heads(v_pool, mesh, -1)
+    bs = k_pool.shape[1]
+    hd = q.shape[-1]
     max_ctx = block_tables.shape[1] * bs
-    kk = gather_paged(k_pool, block_tables, max_ctx)  # (B, T, Hkv, D)
-    vv = gather_paged(v_pool, block_tables, max_ctx)
+    kk = gather_paged(k_pool, block_tables, max_ctx, hd)  # (B, T, Hkv, D)
+    vv = gather_paged(v_pool, block_tables, max_ctx, hd)
     b = x.shape[0]
     kv_pos = jnp.broadcast_to(
         jnp.arange(max_ctx, dtype=jnp.int32), (b, max_ctx)
@@ -526,8 +532,8 @@ def paged_decode_attention(
     k_pool, v_pool = append_paged(
         pool["k"], pool["v"], k[:, 0], v[:, 0], block_tables, positions[:, 0]
     )
-    k_pool = shard_paged_heads(k_pool, mesh, 1)
-    v_pool = shard_paged_heads(v_pool, mesh, 1)
+    k_pool = shard_paged_heads(k_pool, mesh, -1)
+    v_pool = shard_paged_heads(v_pool, mesh, -1)
     out = kernel_ops.paged_attention(
         q[:, 0], k_pool, v_pool, block_tables, positions[:, 0] + 1,
         logit_softcap=cfg.logit_softcap, mesh=mesh,
@@ -572,21 +578,25 @@ def paged_ragged_attention(
     cfg: ModelConfig,
     p: Params,
     x: jnp.ndarray,  # (1, T, d_model) — flattened ragged token batch
-    pool: Dict[str, jnp.ndarray],
+    pool: Dict[str, jnp.ndarray],  # stacked (layers, N, bs, Hkv·D) leaves
+    layer: jnp.ndarray,  # () int32 — this layer's index in the stack
     block_tables: jnp.ndarray,  # (S, M)
     positions: jnp.ndarray,  # (1, T) absolute position of each flat token
     meta: RaggedMeta,
     mesh=None,  # tensor-parallel serving mesh (DESIGN.md §11)
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """Fused mixed-batch attention against the shared paged pool.
+    """Fused mixed-batch attention against layer ``layer`` of the stacked
+    paged pool, which it updates in place and returns whole.
 
     Projects/ropes the whole flattened batch at once, scatters every new
-    token's KV into the pool in ONE fused write (prefill chunks and decode
-    tokens alike — ``cache_ops.write_ragged``), then dispatches the single
-    ragged paged-attention kernel: Pallas on TPU (shard_mapped over KV
-    heads on a mesh), the ``cache_ops`` jnp oracle on CPU.  The padded
-    (S, Qmax) query layout exists only inside the attention op; the output
-    is gathered straight back to the flat token axis.
+    token's KV into the layer in ONE fused write (prefill chunks and decode
+    tokens alike — ``cache_ops.write_ragged`` on the stack seen as one
+    pool of layers · N rows, a free reshape), then dispatches the single
+    ragged paged-attention kernel on the same layer index: Pallas on TPU
+    (shard_mapped over KV heads on a mesh), the ``cache_ops`` jnp oracle on
+    CPU.  Nothing slices the layer out of the stack.  The padded (S, Qmax)
+    query layout exists only inside the attention op; the output is
+    gathered straight back to the flat token axis.
     """
     from repro.kernels import ops as kernel_ops
     from repro.kvcache.cache_ops import write_ragged
@@ -598,15 +608,21 @@ def paged_ragged_attention(
     q = shard_paged_heads(q, mesh, 2)
     k = shard_paged_heads(k, mesh, 2)
     v = shard_paged_heads(v, mesh, 2)
+    # the stack seen as one pool of layers · N rows (a free reshape of its
+    # row-major layout); this layer's rows start at layer · N
+    stack = pool["k"].shape  # (layers, N, bs, Hkv·D)
+    flat = (stack[0] * stack[1],) + stack[2:]
+    rows = jnp.where(meta.dst_row >= 0, meta.dst_row + layer * stack[1], -1)
     k_pool, v_pool = write_ragged(
-        pool["k"], pool["v"], k[0], v[0], meta.dst_row, meta.dst_off
+        pool["k"].reshape(flat), pool["v"].reshape(flat), k[0], v[0], rows,
+        meta.dst_off,
     )
-    k_pool = shard_paged_heads(k_pool, mesh, 1)
-    v_pool = shard_paged_heads(v_pool, mesh, 1)
+    k_pool = shard_paged_heads(k_pool.reshape(stack), mesh, -1)
+    v_pool = shard_paged_heads(v_pool.reshape(stack), mesh, -1)
     q_pad = jnp.take(q[0], meta.qpad, axis=0)  # (S, Qmax, H, D)
     out = kernel_ops.ragged_paged_attention(
         q_pad, k_pool, v_pool, block_tables, meta.q_pos, meta.kv_lens,
-        logit_softcap=cfg.logit_softcap, mesh=mesh,
+        layer=layer, logit_softcap=cfg.logit_softcap, mesh=mesh,
     )
     out = replicate_on_mesh(out, mesh)
     flat = out[meta.unpad_seq, meta.unpad_j][None]  # (1, T, H, D)

@@ -168,17 +168,19 @@ def init_paged_pools(
 ) -> Dict[str, PyTree]:
     """Shared physical KV pools, one {"k","v"} pair per pattern position.
 
-    Leaves are (num_periods, num_blocks, Hkv, block_size, D) — the same
+    Leaves are (num_periods, num_blocks, block_size, Hkv·D) — the same
     period-major stacking as params/caches, so the period scan and the
-    segment slicing helpers apply unchanged; KV heads sit outside the
-    block so the attention kernel loads one head's page as a
-    ``(block_size, D)`` tile (``kernels.paged_attention``).  Every resident
-    sequence lives in these pools, addressed via block tables of physical
-    block ids."""
+    segment slicing helpers apply unchanged.  A page is a lane-dense
+    ``(block_size, Hkv·D)`` tile, head-major along the lanes: the chip's
+    default layout for this shape is its plain row-major tiling, which is
+    what the attention kernel DMAs and the KV scatter writes, so no program
+    that takes the pools relays them out (``kernels.paged_attention``).
+    Every resident sequence lives in these pools, addressed via block
+    tables of physical block ids."""
     if not supports_paged(cfg):
         raise ValueError(f"{cfg.name}: paged pools require plain causal KV")
-    hd = cfg.resolved_head_dim
-    shape = (cfg.num_periods, num_blocks, cfg.num_kv_heads, block_size, hd)
+    width = cfg.num_kv_heads * cfg.resolved_head_dim
+    shape = (cfg.num_periods, num_blocks, block_size, width)
     return {
         str(i): {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
         for i, _ in enumerate(cfg.layer_pattern())
@@ -253,6 +255,7 @@ def _apply_layer(
     capacity_factor: float,
     block_tables: Optional[jnp.ndarray] = None,  # paged physical layout
     ragged: Optional[RaggedMeta] = None,  # fused ragged token batch (§12)
+    layer: Optional[jnp.ndarray] = None,  # ragged: index in the pool stack
     mesh=None,  # tensor-parallel serving mesh (paged path only, §11)
 ) -> Tuple[jnp.ndarray, Optional[PyTree], jnp.ndarray]:
     """Returns (x_out, new_cache, aux_loss).
@@ -263,7 +266,9 @@ def _apply_layer(
       prefill — chunk with prior context in caches (ConServe chunked prefill).
       decode  — one token against caches.
       ragged  — fused mixed token batch on the paged layout (``ragged`` set):
-                prefill chunks and decode tokens share one flattened axis.
+                prefill chunks and decode tokens share one flattened axis;
+                ``cache`` is the whole stacked pool, updated in place at
+                ``layer``.
     """
     from repro.distributed.act_sharding import (
         constrain_block_input,
@@ -293,8 +298,8 @@ def _apply_layer(
         if block_tables is not None:  # shared paged pool (serving hot path)
             if ragged is not None:  # fused mixed batch (one dispatch, §12)
                 mix, new_cache = paged_ragged_attention(
-                    cfg, lp["mixer"], h, cache, block_tables, positions,
-                    ragged, mesh=mesh,
+                    cfg, lp["mixer"], h, cache, layer, block_tables,
+                    positions, ragged, mesh=mesh,
                 )
             else:
                 attn_fn = (
@@ -383,10 +388,13 @@ def run_periods(
     capacity_factor: float = 1.25,
     remat: bool = False,
     block_tables: Optional[jnp.ndarray] = None,  # paged: caches are pools
-    ragged: Optional[RaggedMeta] = None,  # fused ragged token batch (§12)
     mesh=None,  # tensor-parallel serving mesh (paged path only, §11)
 ) -> Tuple[jnp.ndarray, Optional[Dict[str, PyTree]], jnp.ndarray]:
-    """Scan the pattern periods. Returns (x, new_caches, total_aux)."""
+    """Scan the pattern periods. Returns (x, new_caches, total_aux).
+
+    Caches ride the scan as per-period slices in and out, which is a copy
+    of them; the fused serving path carries its pools instead
+    (``run_layers_in_place``)."""
     pattern = cfg.layer_pattern()
 
     from repro.distributed.act_sharding import constrain_residual
@@ -410,7 +418,6 @@ def run_periods(
                 img_x=img_x,
                 capacity_factor=capacity_factor,
                 block_tables=block_tables,
-                ragged=ragged,
                 mesh=mesh,
             )
             if cache_in is not None:
@@ -715,6 +722,68 @@ def run_segment_paged_at(
 # ---------------------------------------------------------------------------
 
 
+def run_layers_in_place(
+    cfg: ModelConfig,
+    layers: PyTree,  # the n periods' weights, leaves stacked (n, ...)
+    x: jnp.ndarray,  # (1, T, d) flattened ragged activations
+    pools: Dict[str, PyTree],  # stacked (P', N, bs, Hkv·D) leaves
+    p_lo: jnp.ndarray,  # index of the first period's KV in the pool stack
+    block_tables: jnp.ndarray,  # (S, M)
+    positions: jnp.ndarray,  # (1, T)
+    meta: RaggedMeta,
+    mesh=None,
+) -> Tuple[jnp.ndarray, Dict[str, PyTree]]:
+    """The fused ragged batch through the periods of ``layers``, updating
+    the donated pools in place.
+
+    The pools are loop state, not scanned slices: period ``i`` scatters
+    its new K/V at ``(p_lo + i, row, slot)`` of the carried stack, and the
+    attention kernel takes ``p_lo + i`` as a scalar-prefetch index and DMAs
+    that layer's pages straight out of it.  No period slice is cut or
+    written back around the loop and no per-layer slice is materialized,
+    so the program moves no pool bytes it neither writes nor attends to."""
+    pattern = cfg.layer_pattern()
+
+    from repro.distributed.act_sharding import constrain_residual
+
+    def body(i, carry):
+        x, pools = carry
+        x = constrain_residual(x)  # seq-parallel residual (no-op if inactive)
+        lp = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), layers
+        )
+        new = {}
+        for j, spec in enumerate(pattern):
+            x, new[str(j)], _ = _apply_layer(
+                cfg,
+                spec,
+                lp[str(j)],
+                x,
+                pools[str(j)],
+                mode="ragged",
+                positions=positions,
+                valid=None,
+                img_x=None,
+                capacity_factor=-1.0,
+                block_tables=block_tables,
+                ragged=meta,
+                layer=p_lo + i,
+                mesh=mesh,
+            )
+        return x, new
+
+    n = jax.tree.leaves(layers)[0].shape[0]
+    return jax.lax.fori_loop(0, n, body, (x, pools))
+
+
+def _segment_layers(params: PyTree, lo: jnp.ndarray, n: int) -> PyTree:
+    """The weights of periods ``[lo, lo + n)``, cut once per segment."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_slice_in_dim(a, lo, n, axis=0),
+        params["layers"],
+    )
+
+
 def run_tokens_paged(
     cfg: ModelConfig,
     params: PyTree,
@@ -735,17 +804,9 @@ def run_tokens_paged(
     sequence's last real token come back for sampling."""
     pools = constrain_paged_pools(pools, mesh)
     x = embed(cfg, params, tokens[None])
-    x, pools, _ = run_periods(
-        cfg,
-        params["layers"],
-        x,
-        mode="ragged",
-        positions=positions[None],
-        caches=pools,
-        block_tables=block_tables,
-        ragged=meta,
-        capacity_factor=-1.0,
-        mesh=mesh,
+    x, pools = run_layers_in_place(
+        cfg, params["layers"], x, pools, jnp.int32(0), block_tables,
+        positions[None], meta, mesh=mesh,
     )
     pools = constrain_paged_pools(pools, mesh)
     return ragged_lm_head(cfg, params, x, logit_index), pools
@@ -763,26 +824,21 @@ def run_tokens_paged_at(
     meta: RaggedMeta,
     mesh=None,  # tensor-parallel serving mesh (DESIGN.md §11)
 ) -> Tuple[jnp.ndarray, Dict[str, PyTree]]:
-    """One K-layer segment of the fused ragged batch, with a *traced*
-    starting period — the fused twin of ``run_segment_paged_at``: all
-    equal-length segments share one compiled program, so the engine's
-    safepoint-instrumented fused iteration costs at most two compilations
-    per (token, sequence, query-length) bucket triple.  Pool writes of an
-    aborted iteration land at not-yet-committed positions and are
-    rewritten verbatim on re-execution (§12 abort soundness)."""
+    """One K-layer segment of the fused ragged batch on the whole donated
+    pool, with a *traced* starting period — the fused twin of
+    ``run_segment_paged_at``: all equal-length segments share one compiled
+    program, so the engine's safepoint-instrumented fused iteration costs
+    at most two compilations per (token, sequence, query-length) bucket
+    triple.  The segment's layers update the pool in place at periods
+    ``lo ..`` (``run_layers_in_place``).  Pool writes of an aborted
+    iteration land at not-yet-committed positions and are rewritten
+    verbatim on re-execution (§12 abort soundness)."""
     pools = constrain_paged_pools(pools, mesh)
-    sl = lambda a: jax.lax.dynamic_slice_in_dim(a, lo, seg_periods, axis=0)
-    ps = jax.tree.map(sl, pools)
-    x, ps_new = run_tokens_paged_seg(
-        cfg, params, seg_periods, lo, x, ps, block_tables, positions,
-        meta, mesh=mesh,
+    x, pools = run_layers_in_place(
+        cfg, _segment_layers(params, lo, seg_periods), x, pools, lo,
+        block_tables, positions, meta, mesh=mesh,
     )
-    merged = jax.tree.map(
-        lambda a, u: jax.lax.dynamic_update_slice_in_dim(a, u, lo, axis=0),
-        pools,
-        ps_new,
-    )
-    return x, constrain_paged_pools(merged, mesh)
+    return x, constrain_paged_pools(pools, mesh)
 
 
 def run_tokens_paged_seg(
@@ -798,7 +854,9 @@ def run_tokens_paged_seg(
     mesh=None,
 ) -> Tuple[jnp.ndarray, Dict[str, PyTree]]:
     """One K-layer segment operating on *its own period slice* of the
-    pools: takes the slice, returns the updated slice.
+    pools: takes the slice, returns the updated slice.  The same in-place
+    loop as the whole-pool form, with the weights read from period ``lo``
+    on and the slice's own layers from 0.
 
     Segments partition the period axis, so a segment never reads another
     segment's slice — keeping the pools permanently split per segment
@@ -806,25 +864,15 @@ def run_tokens_paged_seg(
     whole-pool form above.  The payoff is donation that composes with
     async dispatch: each slice is donated to the segment that owns it,
     whose previous donation hold (the same segment, one iteration ago)
-    has long retired by the time the host enqueues — so the update is
-    in-place with no whole-pool read/write-back traffic and no host
-    stall on the CPU client's donation holds."""
+    has long retired by the time the host enqueues — so the host never
+    stalls on the CPU client's donation holds.  Both forms update their
+    donated pool in place."""
     pool_seg = constrain_paged_pools(pool_seg, mesh)
-    sl = lambda a: jax.lax.dynamic_slice_in_dim(a, lo, seg_periods, axis=0)
-    lp = jax.tree.map(sl, params["layers"])
-    x, ps_new, _ = run_periods(
-        cfg,
-        lp,
-        x,
-        mode="ragged",
-        positions=positions,
-        caches=pool_seg,
-        block_tables=block_tables,
-        ragged=meta,
-        capacity_factor=-1.0,
-        mesh=mesh,
+    x, pool_seg = run_layers_in_place(
+        cfg, _segment_layers(params, lo, seg_periods), x, pool_seg,
+        jnp.int32(0), block_tables, positions, meta, mesh=mesh,
     )
-    return x, constrain_paged_pools(ps_new, mesh)
+    return x, constrain_paged_pools(pool_seg, mesh)
 
 
 def ragged_lm_head(

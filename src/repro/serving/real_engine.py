@@ -10,7 +10,7 @@ recompute path are exact.
 
 Implementation notes:
 * Physical KV layout is the *paged* shared pool (DESIGN.md §5): per-layer
-  pools ``(num_device_blocks+1, Hkv, block_size, D)`` addressed via block
+  pools ``(num_device_blocks+1, block_size, Hkv·D)`` addressed via block
   tables built from the BlockManager's physical block ids; attention
   dispatches to the Pallas ``ragged_paged_attention`` kernel on TPU and the
   ``cache_ops`` jnp oracle on CPU.  The last pool row is a scratch block that absorbs writes
@@ -20,7 +20,8 @@ Implementation notes:
   online decodes plus offline prefill chunks — lowers to ONE flattened
   ragged token batch (``_build_ragged``) and executes as a single
   ``run_tokens_paged_at`` dispatch per K-layer segment, each layer doing
-  one fused KV-pool scatter and one ragged paged-attention op; decode is
+  one fused KV-pool scatter and one ragged paged-attention op on the
+  donated pool in place; decode is
   the ``q_len = 1`` degenerate case, not a separate dispatch family.
   ``fused_batch=False`` keeps the split per-family paths below as the
   differential oracle.
@@ -501,7 +502,8 @@ class RealEngine:
             # permanently SPLIT per segment (``_pool_segs``): each
             # segment donates only its own slice, whose previous hold
             # (the same segment, one iteration ago) retired long before
-            # the host enqueues — in-place updates AND real overlap.  The
+            # the host enqueues — real overlap.  Both forms update their
+            # donated pool in place (transformer.run_layers_in_place).  The
             # displaced slice references are parked in ``_retired`` until
             # their holds provably resolved (see _drop_retired).
             def _fused_segment_seg(pps, params, lo, x, pool_seg, tables,
@@ -790,7 +792,7 @@ class RealEngine:
             self._pool_segs = list(
                 self._restore_segs_jit(tuple(displaced), ids, batched)
             )
-            witness = jax.tree.leaves(self._pool_segs[0])[0][0, 0, 0, 0, 0]
+            witness = jax.tree.leaves(self._pool_segs[0])[0][0, 0, 0, 0]
             self._retired.append((witness, displaced))
         else:
             self.pools = self._restore_jit(self.pools, ids, batched)
@@ -823,7 +825,7 @@ class RealEngine:
             self._pool_segs = list(
                 self._cow_segs_jit(tuple(displaced), src, dst)
             )
-            witness = jax.tree.leaves(self._pool_segs[0])[0][0, 0, 0, 0, 0]
+            witness = jax.tree.leaves(self._pool_segs[0])[0][0, 0, 0, 0]
             self._retired.append((witness, displaced))
         else:
             self.pools = self._cow_jit(self.pools, src, dst)

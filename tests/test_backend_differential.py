@@ -42,9 +42,19 @@ from repro.models import transformer as tf
 from repro.serving.real_engine import RealEngine, RealEngineConfig
 
 
+# Reduced configs beyond ``ModelConfig.reduced()``'s defaults: a deeper
+# stack whose fused segments run the in-place layer loop over two periods
+# (at pool offsets 0 and 2) and a one-period tail segment at offset 4.
+# No paged architecture has a layer pattern of more than one position.
+VARIANTS = {
+    "qwen2-0.5b-deep": ("qwen2-0.5b", dict(num_layers=5, safepoint_interval=2)),
+}
+
+
 @functools.lru_cache(maxsize=None)
 def _model(arch: str):
-    cfg = get_config(arch).reduced()
+    base, overrides = VARIANTS.get(arch, (arch, {}))
+    cfg = get_config(base).reduced(**overrides)
     return cfg, tf.init_params(cfg, jax.random.PRNGKey(0))
 
 
@@ -106,6 +116,10 @@ CASES = [
     # heads over at most 2 devices, each keeping its whole query group
     ("qwen2-0.5b", [(40, 8), (20, 8)], None, {}),
     ("qwen2-0.5b", [(40, 10), (24, 6), (40, 6), (20, 4)], 4,
+     dict(num_device_blocks=14)),
+    # multi-period segments: preempt/resume restores and extracts through
+    # every pool layer the in-place loop writes
+    ("qwen2-0.5b-deep", [(40, 10), (24, 6), (40, 6), (20, 4)], 4,
      dict(num_device_blocks=14)),
 ]
 
@@ -358,7 +372,8 @@ def test_shared_prefix_mid_iteration_abort_is_exact():
 def test_sharded_pool_is_actually_sharded():
     """On a multi-device mesh the MHA pool must shard its KV-head axis
     (the memory win tensor parallelism exists for), each shard holding
-    Hkv/tp heads; a 1-device mesh leaves it whole."""
+    Hkv/tp whole heads of the head-major page lanes; a 1-device mesh
+    leaves it whole."""
     cfg, params = _model("llama-2-7b")
     tp = _tp()
     eng = RealEngine(
@@ -367,9 +382,11 @@ def test_sharded_pool_is_actually_sharded():
     )
     spec = eng.pools["0"]["k"].sharding.spec
     if tp > 1:
-        assert spec[2] == "model", spec
+        assert spec[3] == "model", spec
         shard = next(iter(eng.pools["0"]["k"].addressable_shards))
-        assert shard.data.shape[2] == cfg.num_kv_heads // tp
+        assert shard.data.shape[3] == (
+            cfg.num_kv_heads // tp * cfg.resolved_head_dim
+        )
     else:
         assert all(s is None for s in spec)
 

@@ -22,6 +22,13 @@ def _rand(shape, dtype, i=0):
     return jax.random.normal(jax.random.fold_in(KEY, i), shape).astype(dtype)
 
 
+def _pool(npages, page, hkv, d, i, dtype=jnp.float32, layers=None):
+    """A paged pool in the served layout: (N, page, Hkv·D) per layer, or a
+    stack of ``layers`` of them."""
+    lead = () if layers is None else (layers,)
+    return _rand(lead + (npages, page, hkv * d), dtype, i)
+
+
 # ------------------------------------------------------------- flash prefill
 
 FLASH_CASES = [
@@ -67,8 +74,8 @@ PAGED_CASES = [
 def test_paged_attention_matches_ref(case):
     b, h, hkv, d, page, npages, m = case
     q = _rand((b, h, d), jnp.float32, 4)
-    kp = _rand((npages, hkv, page, d), jnp.float32, 5)
-    vp = _rand((npages, hkv, page, d), jnp.float32, 6)
+    kp = _pool(npages, page, hkv, d, 5)
+    vp = _pool(npages, page, hkv, d, 6)
     key = jax.random.fold_in(KEY, 7)
     # random non-overlapping page assignment with ragged lengths
     perm = jax.random.permutation(key, npages)[: b * m].reshape(b, m)
@@ -92,8 +99,8 @@ def test_paged_attention_matches_ref(case):
 def test_paged_attention_property(b, g, hkv, page, m):
     h, d, npages = hkv * g, 32, 24
     q = _rand((b, h, d), jnp.float32, 10)
-    kp = _rand((npages, hkv, page, d), jnp.float32, 11)
-    vp = _rand((npages, hkv, page, d), jnp.float32, 12)
+    kp = _pool(npages, page, hkv, d, 11)
+    vp = _pool(npages, page, hkv, d, 12)
     key = jax.random.fold_in(KEY, 13)
     perm = jax.random.permutation(key, npages)[: b * m].reshape(b, m)
     lens = jax.random.randint(jax.random.fold_in(KEY, 14), (b,), 1, m * page + 1)
@@ -105,32 +112,39 @@ def test_paged_attention_property(b, g, hkv, page, m):
 # -------------------------------------------------------------- fused ragged
 
 RAGGED_CASES = [
-    # q_lens per sequence (mixed chunks + decodes), h, hkv, d, page, m
-    ([1, 1, 1], 8, 2, 64, 16, 4),  # pure decode (q_len = 1 degenerate case)
-    ([8, 1, 4, 1], 4, 2, 32, 8, 6),  # mixed prefill chunks + decodes
-    ([6, 3], 4, 4, 32, 8, 4),  # dense (g = 1) ragged chunks
-    ([5, 1], 16, 1, 64, 16, 3),  # MQA
-    ([130, 1], 2, 1, 32, 16, 10),  # Qmax > QUERY_TILE: tiled, padded rows
+    # q_lens per sequence (mixed chunks + decodes), h, hkv, d, page, m,
+    # (layers in the pool stack, layer the kernel reads)
+    ([1, 1, 1], 8, 2, 64, 16, 4, (3, 0)),  # pure decode (q_len = 1), GQA
+    ([1, 1, 1], 8, 2, 64, 16, 4, (3, 1)),
+    ([1, 1, 1], 8, 2, 64, 16, 4, (3, 2)),
+    ([8, 1, 4, 1], 4, 2, 32, 8, 6, (3, 0)),  # mixed chunks + decodes, GQA
+    ([8, 1, 4, 1], 4, 2, 32, 8, 6, (3, 1)),
+    ([8, 1, 4, 1], 4, 2, 32, 8, 6, (3, 2)),
+    ([6, 3], 4, 4, 32, 8, 4, (1, 0)),  # dense (g = 1) ragged chunks
+    ([5, 1], 16, 1, 64, 16, 3, (1, 0)),  # MQA
+    ([130, 1], 2, 1, 32, 16, 10, (1, 0)),  # Qmax > QUERY_TILE: tiled, padded
 ]
 
 
 @pytest.mark.parametrize("case", RAGGED_CASES)
 @pytest.mark.parametrize("softcap", [0.0, 30.0])
-def test_ragged_paged_attention_matches_ref(case, softcap):
-    """The fused mixed-batch kernel (interpret mode) vs the jnp oracle:
-    one grid covers prefill chunks and decode rows; each sequence's
-    queries sit at the tail of its context (the serve-time layout).
-    Padded query slots are compared too — the kernel and oracle mask them
+def test_ragged_paged_attention_matches_ref(case, softcap, monkeypatch):
+    """The fused mixed-batch kernel (interpret mode, through the backend
+    dispatcher) vs the jnp oracle: one grid covers prefill chunks and
+    decode rows; each sequence's queries sit at the tail of its context
+    (the serve-time layout).  The kernel reads layer ``layer`` of a stacked
+    pool by its scalar-prefetched index; the oracle gets that layer's
+    slice — first, middle and last layer of a three-layer stack.  Padded
+    query slots are compared too — the kernel and oracle mask them
     identically via the causal + kv_len bound."""
-    from repro.kernels.paged_attention import ragged_paged_attention
-
-    q_lens, h, hkv, d, page, m = case
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "interpret")
+    q_lens, h, hkv, d, page, m, (layers, layer) = case
     s = len(q_lens)
     qmax = max(q_lens)
     npages = s * m
     q = _rand((s, qmax, h, d), jnp.float32, 40)
-    kp = _rand((npages, hkv, page, d), jnp.float32, 41)
-    vp = _rand((npages, hkv, page, d), jnp.float32, 42)
+    kp = _pool(npages, page, hkv, d, 41, layers=layers)
+    vp = _pool(npages, page, hkv, d, 42, layers=layers)
     key = jax.random.fold_in(KEY, 43)
     perm = jax.random.permutation(key, npages)[: s * m].reshape(s, m)
     kv_lens = jax.random.randint(
@@ -141,12 +155,12 @@ def test_ragged_paged_attention_matches_ref(case, softcap):
     ql = jnp.asarray(q_lens)
     j = jnp.arange(qmax)[None, :]
     q_pos = kv_lens[:, None] - ql[:, None] + jnp.minimum(j, ql[:, None] - 1)
-    out = ragged_paged_attention(
-        q, kp, vp, perm, q_pos, kv_lens, logit_softcap=softcap,
-        interpret=True,
+    out = ops.ragged_paged_attention(
+        q, kp, vp, perm, q_pos, kv_lens, layer=jnp.int32(layer),
+        logit_softcap=softcap,
     )
     want = ref.ragged_paged_attention_ref(
-        q, kp, vp, perm, q_pos, kv_lens, logit_softcap=softcap
+        q, kp[layer], vp[layer], perm, q_pos, kv_lens, logit_softcap=softcap
     )
     assert float(jnp.max(jnp.abs(out - want))) < 2e-5
 
@@ -160,15 +174,16 @@ def test_ragged_kernel_decode_degenerates_to_paged_attention():
 
     b, h, hkv, d, page, m, npages = 2, 8, 2, 64, 16, 3, 8
     q = _rand((b, h, d), jnp.float32, 50)
-    kp = _rand((npages, hkv, page, d), jnp.float32, 51)
-    vp = _rand((npages, hkv, page, d), jnp.float32, 52)
+    kp = _pool(npages, page, hkv, d, 51)
+    vp = _pool(npages, page, hkv, d, 52)
     perm = jax.random.permutation(jax.random.fold_in(KEY, 53), npages)[
         : b * m
     ].reshape(b, m)
     lens = jnp.array([37, 12], jnp.int32)
     dec = paged_attention(q, kp, vp, perm, lens, interpret=True)
     rag = ragged_paged_attention(
-        q[:, None], kp, vp, perm, (lens - 1)[:, None], lens, interpret=True
+        q[:, None], kp[None], vp[None], perm, (lens - 1)[:, None], lens,
+        layer=jnp.int32(0), interpret=True,
     )
     assert float(jnp.max(jnp.abs(rag[:, 0] - dec))) < 2e-5
 
@@ -178,14 +193,14 @@ def test_ragged_kernel_decode_degenerates_to_paged_attention():
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_checkpoint_gather_matches_ref(dtype):
-    pool = _rand((32, 2, 16, 64), dtype, 20)
+    pool = _pool(32, 16, 2, 64, 20, dtype)
     ids = jnp.array([5, 2, 17, 9, 31], jnp.int32)
     out = checkpoint_gather(pool, ids, interpret=True)
     assert jnp.array_equal(out, ref.checkpoint_gather_ref(pool, ids))
 
 
 def test_checkpoint_scatter_roundtrip():
-    pool = _rand((32, 2, 16, 64), jnp.float32, 21)
+    pool = _pool(32, 16, 2, 64, 21)
     ids = jnp.array([3, 8, 1], jnp.int32)
     staged = checkpoint_gather(pool, ids, interpret=True)
     wiped = pool.at[ids].set(0.0)

@@ -88,8 +88,8 @@ def test_write_paged_chunk_matches_append_order():
     would."""
     key = jax.random.PRNGKey(2)
     bs, nblk, hkv, d = 4, 8, 2, 16
-    k_pool = jnp.zeros((nblk, hkv, bs, d))
-    v_pool = jnp.zeros((nblk, hkv, bs, d))
+    k_pool = jnp.zeros((nblk, bs, hkv * d))
+    v_pool = jnp.zeros((nblk, bs, hkv * d))
     tables = jnp.array([[5, 2, 7, -1], [1, 6, -1, -1]], jnp.int32)
     k_new = jax.random.normal(key, (2, 6, hkv, d))
     v_new = jax.random.normal(jax.random.fold_in(key, 1), (2, 6, hkv, d))
@@ -109,8 +109,8 @@ def test_write_paged_chunk_matches_append_order():
 def test_scatter_drops_writes_through_padding():
     """Writes addressed through -1 table entries (or beyond the table) must
     be dropped, never aliased onto a real block."""
-    k_pool = jnp.zeros((4, 1, 2, 4))
-    v_pool = jnp.zeros((4, 1, 2, 4))
+    k_pool = jnp.zeros((4, 2, 4))
+    v_pool = jnp.zeros((4, 2, 4))
     tables = jnp.array([[2, -1]], jnp.int32)
     ones = jnp.ones((1, 1, 1, 4))
     # token at position 3 -> padded column 1 -> dropped
@@ -142,7 +142,7 @@ def test_max_model_len_not_multiple_of_block_size():
 
 
 def test_extract_write_block_roundtrip():
-    pool = jax.random.normal(jax.random.PRNGKey(3), (8, 4, 2, 16))
+    pool = jax.random.normal(jax.random.PRNGKey(3), (8, 2, 64))
     blk = cache_ops.extract_block(pool, 5)
     wiped = cache_ops.write_block(pool, 5, jnp.zeros_like(blk))
     assert float(jnp.max(jnp.abs(wiped[5]))) == 0.0
@@ -156,8 +156,8 @@ def test_paged_attention_ref_softcap():
 
     key = jax.random.PRNGKey(4)
     q = jax.random.normal(key, (2, 4, 32))
-    kp = jax.random.normal(jax.random.fold_in(key, 1), (8, 2, 8, 32))
-    vp = jax.random.normal(jax.random.fold_in(key, 2), (8, 2, 8, 32))
+    kp = jax.random.normal(jax.random.fold_in(key, 1), (8, 8, 2 * 32))
+    vp = jax.random.normal(jax.random.fold_in(key, 2), (8, 8, 2 * 32))
     tables = jnp.array([[0, 3, 6], [1, 4, -1]], jnp.int32)
     lens = jnp.array([20, 11], jnp.int32)
     out = paged_attention(
