@@ -3,13 +3,18 @@ shapes -- the benchmark's own arithmetic, kept apart from the program's.
 
 An iteration's plan is a list of ``(q_len, ctx_start, online, sampled)``
 items: a prefill chunk of ``q_len`` tokens after ``ctx_start`` cached ones,
-or a decode (``q_len`` 1); ``sampled`` when the item yields a token.  Only useful work is counted: no padding to buckets,
-and causal attention over the keys each query may see.
+or a decode (``q_len`` 1); ``sampled`` when the item yields a token.  Only
+useful work is counted: no padding to buckets, and causal attention over
+the keys each query may see.  What a model family adds -- its matmuls per
+token, its head and its kernels' work -- is its own ``costs.py``
+(``bench/family.py``).
 """
 from __future__ import annotations
 
 import json
 import os
+
+from bench import family
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -30,30 +35,27 @@ def attention_pairs(q: int, ctx: int) -> int:
     return q * ctx + q * (q + 1) // 2
 
 
-def attention_cost(m: dict, items, kv_bytes: int = 4) -> tuple:
-    """(flops, bytes) the ragged attention kernel needs for one plan, over
-    all layers: QK^T and PV on every visible pair; reading each sequence's
-    KV once, its queries once and writing its outputs once."""
-    h, kvh, hd, L = m["heads"], m["kv_heads"], m["head_dim"], m["layers"]
-    flops = nbytes = 0
-    for q, ctx, _online, _sampled in items:
-        flops += 4 * h * hd * attention_pairs(q, ctx)
-        nbytes += 2 * (ctx + q) * kvh * hd * kv_bytes + 2 * q * h * hd * 4
-    return flops * L, nbytes * L
+def _family(m: dict):
+    return family.module(m["family"], "costs")
 
 
-def matmul_flops_per_token(m: dict) -> int:
-    """Weight matmul operations of one token through every layer."""
-    d, hd, f = m["d_model"], m["head_dim"], m["d_ff"]
-    h, kvh = m["heads"], m["kv_heads"]
-    per_layer = d * (h + 2 * kvh) * hd + h * hd * d + 3 * d * f
-    return 2 * per_layer * m["layers"]
+def kernels(m: dict) -> dict:
+    """The kernels the model's family prices: op family -> cost function."""
+    return _family(m).KERNELS
+
+
+def kernel_cost(m: dict, kernel: str, items) -> tuple:
+    """(flops, bytes) ``kernel`` needs for one plan, by the model's family;
+    a kernel the family does not price is an error."""
+    return kernels(m)[kernel](m, items)
 
 
 def step_flops(m: dict, items) -> int:
     """Model operations of one plan: every token through the layers, the
-    attention it needs, and the head on each token that is sampled."""
+    work of the family's kernels (attention), and the head on each token
+    that is sampled."""
+    fam = _family(m)
     tokens = sum(it[0] for it in items)
-    attn, _ = attention_cost(m, items)
-    head = 2 * m["d_model"] * m["vocab"] * sum(1 for it in items if it[3])
-    return tokens * matmul_flops_per_token(m) + attn + head
+    kernel = sum(cost(m, items)[0] for cost in fam.KERNELS.values())
+    head = fam.head_flops(m) * sum(1 for it in items if it[3])
+    return tokens * fam.matmul_flops_per_token(m) + kernel + head

@@ -1,7 +1,7 @@
 """Whole step: model operations of the window's completed iterations
-(``bench/costs.py``: every token through every layer, its causal
-attention, the head on each sampled token) over the window's length times
-the chip's peak."""
+(``bench/costs.py step_flops``: every token through every layer, the work
+of the family's kernels -- its causal attention -- and the head on each
+sampled token) over the window's length times the chip's peak."""
 from bench import costs
 
 

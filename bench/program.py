@@ -1,6 +1,7 @@
 """The system under test, as the benchmark drives it.
 
-Everything that touches the program lives here: building its model
+Everything that touches the program lives here or in a model family's
+``program.py`` (``bench/family.py``): building the program's model
 configuration and engine from a configuration file, handing it the weights
 drawn by ``reference.draw`` in the program's own layout, warming up every
 program the traffic can dispatch, and the hooks that record each
@@ -16,7 +17,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from bench import reference
+from bench import family, reference
 
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -30,71 +31,16 @@ def import_program() -> None:
         sys.path.insert(0, src)
 
 
-def model_config(cfg: dict):
-    """The program's ``ModelConfig`` for this configuration file."""
-    from repro.models.config import ModelConfig
-
-    m = reference.dims(cfg)
-    return ModelConfig(
-        name=cfg["name"],
-        arch_type="dense",
-        source=cfg["source"],
-        num_layers=m["layers"],
-        d_model=m["d_model"],
-        num_heads=m["heads"],
-        num_kv_heads=m["kv_heads"],
-        head_dim=m["head_dim"],
-        d_ff=m["d_ff"],
-        vocab_size=m["vocab"],
-        activation="swiglu",
-        qkv_bias=m["qkv_bias"],
-        tie_embeddings=m["tied"],
-        rope_theta=float(m["theta"]),
-        norm_eps=float(m["eps"]),
-        safepoint_interval=cfg["architecture"]["safepoint_interval"],
-    )
-
-
-def program_params(m: dict, w: dict) -> dict:
-    """``reference.draw``'s weights in the program's layout (one period per
-    layer, heads split out of the projection columns)."""
-    L, d, hd = m["layers"], m["d_model"], m["head_dim"]
-    h, kv = m["heads"], m["kv_heads"]
-    mixer = {
-        "wq": w["wq"].reshape(L, d, h, hd),
-        "wk": w["wk"].reshape(L, d, kv, hd),
-        "wv": w["wv"].reshape(L, d, kv, hd),
-        "wo": w["wo"].reshape(L, h, hd, d),
-    }
-    if m["qkv_bias"]:
-        mixer.update(
-            bq=w["bq"].reshape(L, h, hd),
-            bk=w["bk"].reshape(L, kv, hd),
-            bv=w["bv"].reshape(L, kv, hd),
-        )
-    params = {
-        "embed": w["embed"],
-        "final_norm": w["final_norm"],
-        "layers": {
-            "0": {
-                "norm1": w["ln1"],
-                "norm2": w["ln2"],
-                "mixer": mixer,
-                "ffn": {"w_up": w["w_up"], "w_gate": w["w_gate"],
-                        "w_down": w["w_down"]},
-            }
-        },
-    }
-    if "lm_head" in w:
-        params["lm_head"] = w["lm_head"]
-    return params
+def _family(m: dict):
+    return family.module(m["family"], "program")
 
 
 def weights(cfg: dict, seed: int) -> dict:
     """``reference.draw``'s weights for the seed, in the program's layout,
     drawn on the device in one program."""
     m = reference.dims(cfg)
-    return jax.jit(lambda key: program_params(m, reference.draw(m, key)))(
+    fam = _family(m)
+    return jax.jit(lambda key: fam.program_params(m, reference.draw(m, key)))(
         reference.seed_key(seed)
     )
 
@@ -107,8 +53,9 @@ def build_engine(cfg: dict, seed: int):
     from repro.serving.real_engine import RealEngine, RealEngineConfig
 
     sv = cfg["serving"]
+    m = reference.dims(cfg)
     return RealEngine(
-        model_config(cfg),
+        _family(m).model_config(cfg, m),
         weights(cfg, seed),
         sched_cfg=SchedulerConfig(slo_aware=True),
         eng_cfg=RealEngineConfig(

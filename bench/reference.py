@@ -1,22 +1,26 @@
-"""Plain reference of the dense GQA decoder family (Qwen2, Llama-style).
+"""The plain reference: what every model family shares, and the family's
+own parts found by the name its configuration file gives.
 
 Straightforward ``jax.numpy`` in float32 with every matmul at precision
-HIGHEST, written from the published description (HF ``modeling_qwen2`` /
-``modeling_llama``): RMSNorm, rotary embeddings (rotate-half form), grouped
-query attention with optional QKV bias, SwiGLU, tied or untied head.  It
-imports nothing of the program under test.
+HIGHEST.  Each family's equations are ``bench/families/<family>/
+reference.py`` (``bench/family.py``): its sizes (``dims``), its weights
+(``draw``) and its layers (``hidden``).  Here are the parts they share --
+RMSNorm, rotary embeddings, causal attention in blocks of query rows, the
+float8 arithmetic of the control -- and the head over the final hidden
+states, in row blocks, that turns them into the numbers compared.  None of
+it imports anything of the program under test.
 
-It works one sequence at a time and one layer at a time (a scan over the
-stacked layers), and takes the head in row blocks, so a 2048-token sequence
-fits beside nothing else at published widths.
+It works one sequence at a time, one layer at a time, a block of query
+rows at a time and a block of head rows at a time, so a long sequence
+never needs its whole score or logit matrix at once.
 
 ``lowp=True`` is the control: the same mathematics with every matmul's
 operands rounded to float8 (e4m3, absmax scaling per weight matrix and per
 activation row) and float32 accumulation -- one precision step below the
 bfloat16 arithmetic the configurations state.
 
-Weights are drawn here, on the device, from the run's seed (``draw``), with
-every parameter nonzero: biases and norm scales included.
+Weights are drawn on the device from the run's seed (``draw_jit``), every
+parameter nonzero.
 """
 from __future__ import annotations
 
@@ -26,31 +30,34 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import family
+
 HIGHEST = jax.lax.Precision.HIGHEST
 F8 = jnp.float8_e4m3fn
 F8_MAX = 448.0
 HEAD_ROWS = 256  # rows of the head computed at a time
+Q_BLOCK = 256  # query rows of attention computed at a time
+
+
+def _family(m: dict):
+    return family.module(m["family"], "reference")
 
 
 def dims(cfg: dict) -> dict:
-    """The model's sizes from its configuration file (HF key names)."""
-    heads = cfg["num_attention_heads"]
-    d = {
-        "layers": cfg["num_hidden_layers"],
-        "d_model": cfg["hidden_size"],
-        "head_dim": cfg["hidden_size"] // heads,
-        "heads": heads,
-        "kv_heads": cfg["num_key_value_heads"],
-        "d_ff": cfg["intermediate_size"],
-        "vocab": cfg["vocab_size"],
-        "eps": cfg["rms_norm_eps"],
-        "theta": cfg["rope_theta"],
-        "qkv_bias": cfg["architecture"]["qkv_bias"],
-        "tied": cfg["tie_word_embeddings"],
-    }
-    if d["heads"] % d["kv_heads"]:
-        raise ValueError(f"{cfg['name']}: query heads not a multiple of KV heads")
-    return d
+    """The model's sizes from its configuration file, by its family."""
+    return family.module(family.check(cfg), "reference").dims(cfg)
+
+
+def draw(m: dict, key: jax.Array) -> dict:
+    """All weights of the model, float32, from a key of the seed (call
+    under ``jax.jit`` to draw them on the device in one program)."""
+    return _family(m).draw(m, key)
+
+
+def hidden(m: dict, w: dict, tokens, lowp: bool = False):
+    """Final normed hidden states (N, d) of one sequence (no padding
+    before its tokens; positions 0..N-1)."""
+    return _family(m).hidden(m, w, tokens, lowp)
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -60,58 +67,6 @@ def seed_key(seed: int) -> jax.Array:
         raise ValueError("seed must be non-negative")
     key = jax.random.PRNGKey(seed & 0xFFFFFFFF)
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
-
-
-def _shapes(m: dict) -> dict:
-    L, d, hd = m["layers"], m["d_model"], m["head_dim"]
-    h, kv, f, v = m["heads"], m["kv_heads"], m["d_ff"], m["vocab"]
-    s = {
-        "embed": (v, d),
-        "final_norm": (d,),
-        "ln1": (L, d),
-        "ln2": (L, d),
-        "wq": (L, d, h * hd),
-        "wk": (L, d, kv * hd),
-        "wv": (L, d, kv * hd),
-        "wo": (L, h * hd, d),
-        "w_gate": (L, d, f),
-        "w_up": (L, d, f),
-        "w_down": (L, f, d),
-    }
-    if m["qkv_bias"]:
-        s.update(bq=(L, h * hd), bk=(L, kv * hd), bv=(L, kv * hd))
-    if not m["tied"]:
-        s["lm_head"] = (d, v)
-    return s
-
-
-def _scale(name: str, m: dict) -> tuple:
-    """(mean, std) of each parameter: fan-in scaled matrices, norm scales
-    around 1, small biases -- none of them zero."""
-    d, f, hd = m["d_model"], m["d_ff"], m["head_dim"]
-    if name in ("ln1", "ln2", "final_norm"):
-        return 1.0, 0.1
-    if name in ("bq", "bk", "bv"):
-        return 0.0, 0.1
-    if name == "embed":
-        return 0.0, 0.02 if m["tied"] else 1.0
-    if name == "wo":
-        return 0.0, (m["heads"] * hd) ** -0.5
-    if name == "w_down":
-        return 0.0, f**-0.5
-    return 0.0, d**-0.5
-
-
-def draw(m: dict, key: jax.Array) -> dict:
-    """All weights, float32, from a key of the seed (call under
-    ``jax.jit`` to draw them on the device in one program)."""
-    shapes = _shapes(m)
-    keys = jax.random.split(key, len(shapes))
-    out = {}
-    for k, (name, shape) in zip(keys, sorted(shapes.items())):
-        mean, std = _scale(name, m)
-        out[name] = mean + std * jax.random.normal(k, shape, jnp.float32)
-    return out
 
 
 def draw_jit(m: dict, seed: int) -> dict:
@@ -149,7 +104,7 @@ def _einsum8(spec, a, b, a_axis, b_axis):
     return out, sa, sb
 
 
-# ------------------------------------------------------------------ layers
+# ------------------------------------------------------------- shared parts
 def rmsnorm(x, w, eps):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * w
@@ -167,12 +122,26 @@ def rope(x, pos, theta):
 
 
 def attention(q, k, v, lowp: bool):
-    """Causal GQA. q (N, H, hd), k/v (N, Hkv, hd) -> (N, H*hd)."""
+    """Causal GQA. q (N, H, hd), k/v (N, Hkv, hd) -> (N, H*hd), computed
+    ``Q_BLOCK`` query rows at a time: each block attends over every key,
+    those it may not see masked, so no score tensor is larger than one
+    block x N keys x H heads, and each row's arithmetic is that of one
+    unblocked pass."""
     n, h, hd = q.shape
-    kvh = k.shape[1]
-    g = h // kvh
-    qg = q.reshape(n, kvh, g, hd)  # query head i uses KV head i // g
-    scale = hd**-0.5
+    qb = min(Q_BLOCK, n)
+    nb = -(-n // qb)
+    blocks = jnp.pad(q, ((0, nb * qb - n), (0, 0), (0, 0))).reshape(nb, qb, h, hd)
+    starts = jnp.arange(nb, dtype=jnp.int32) * qb
+    out = jax.lax.map(lambda a: _attend(a[0], a[1], k, v, lowp), (blocks, starts))
+    return out.reshape(nb * qb, h * hd)[:n]
+
+
+def _attend(q, start, k, v, lowp: bool):
+    """Query rows ``start`` .. ``start + len(q)`` of causal GQA over all of
+    k/v; query head i uses KV head i // group."""
+    n, h, hd = q.shape
+    m, kvh = k.shape[:2]
+    qg = q.reshape(n, kvh, h // kvh, hd)
     if lowp:
         # q rows scale along hd, k rows along hd; p rows along the keys,
         # v columns along the keys: every scale factors out of its sum
@@ -180,8 +149,8 @@ def attention(q, k, v, lowp: bool):
         s = s * jnp.transpose(sa, (1, 2, 0, 3)) * sb[:, :, 0].T[:, None, None, :]
     else:
         s = jnp.einsum("nkgd,mkd->kgnm", qg, k, precision=HIGHEST)
-    s = s * scale
-    causal = jnp.tril(jnp.ones((n, n), bool))
+    s = s * hd**-0.5
+    causal = (start + jnp.arange(n))[:, None] >= jnp.arange(m)[None, :]
     s = jnp.where(causal, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     if lowp:
@@ -190,43 +159,6 @@ def attention(q, k, v, lowp: bool):
     else:
         o = jnp.einsum("kgnm,mkd->nkgd", p, v, precision=HIGHEST)
     return o.reshape(n, h * hd)
-
-
-def layer(m: dict, x, lw: dict, pos, lowp: bool):
-    hd = m["head_dim"]
-    n = x.shape[0]
-    h = rmsnorm(x, lw["ln1"], m["eps"])
-    q = _mm(h, lw["wq"], lowp)
-    k = _mm(h, lw["wk"], lowp)
-    v = _mm(h, lw["wv"], lowp)
-    if m["qkv_bias"]:
-        q, k, v = q + lw["bq"], k + lw["bk"], v + lw["bv"]
-    q = rope(q.reshape(n, m["heads"], hd), pos, m["theta"])
-    k = rope(k.reshape(n, m["kv_heads"], hd), pos, m["theta"])
-    v = v.reshape(n, m["kv_heads"], hd)
-    x = x + _mm(attention(q, k, v, lowp), lw["wo"], lowp)
-    h = rmsnorm(x, lw["ln2"], m["eps"])
-    gate = jax.nn.silu(_mm(h, lw["w_gate"], lowp))
-    return x + _mm(gate * _mm(h, lw["w_up"], lowp), lw["w_down"], lowp)
-
-
-_LAYER_KEYS = ("ln1", "ln2", "wq", "wk", "wv", "wo", "w_gate", "w_up",
-               "w_down", "bq", "bk", "bv")
-
-
-def hidden(m: dict, w: dict, tokens, lowp: bool = False):
-    """Final normed hidden states (N, d) of one sequence (no padding
-    before its tokens; positions 0..N-1)."""
-    n = tokens.shape[0]
-    pos = jnp.arange(n, dtype=jnp.int32)
-    x = jnp.take(w["embed"], tokens, axis=0)
-    stacked = {k: w[k] for k in _LAYER_KEYS if k in w}
-
-    def body(x, lw):
-        return layer(m, x, lw, pos, lowp), None
-
-    x, _ = jax.lax.scan(body, x, stacked)
-    return rmsnorm(x, w["final_norm"], m["eps"])
 
 
 def _head(w: dict):

@@ -4,7 +4,8 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell (``BENCHMARK.json`` ``workloads``) names a configuration
-(``bench/configs/<config>.json``) and a traffic mix
+(``bench/configs/<config>.json``, which names its model family,
+``bench/families/<family>/``) and a traffic mix
 (``bench/traffic/<mix>.json``).  The run builds the program's engine with
 weights drawn from the seed, calibrates it, warms up every program the
 traffic can dispatch, and serves the mix through the program's own path --
@@ -16,7 +17,9 @@ window finished is compared with the plain reference (``bench/check.py``).
 
 ``--trace 0`` prints the cell's end-to-end metrics, ``--trace 1`` its
 per-layer metrics (each read by ``bench/metrics/<name>.py``) from a run
-that also records a profiler trace of the window's last seconds.  The last
+that also records a profiler trace of the window's last seconds, or of
+as many seconds from the last online arrival where that comes earlier,
+so the traced stretch always holds work.  The last
 line of standard output is one JSON object; the numbers compared, with
 their limits, are the last lines of standard error and the last key of
 that object.  Without a TPU, or with fewer chips than the cell asks for,
@@ -43,7 +46,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from bench import check, costs, program, reference  # noqa: E402
+from bench import check, costs, family, program, reference  # noqa: E402
 from bench import trace as tracing  # noqa: E402
 from bench import traffic as gen  # noqa: E402
 
@@ -53,7 +56,7 @@ TRACE_DIR = os.path.join(ROOT, ".bench_trace")
 # the plans each cell's runs met, warmed up for by the next run (beside the
 # compilation cache: both hold what this checkout has already compiled)
 SHAPES_DIR = os.path.join(CACHE_DIR, "bench-plans")
-TRACE_SECONDS = 5.0  # the traced part of the window: its last seconds
+TRACE_SECONDS = 5.0  # the traced part of the window (``CellRun.serve``)
 DRAIN_S = 60.0  # how long past the window online requests are followed
 COMPILE_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -76,9 +79,17 @@ def load_cell(name: str) -> tuple:
     if name not in cells:
         raise SystemExit(f"no workload {name!r} in BENCHMARK.json")
     cell = cells[name]
-    cfg = load_json(BENCH, "configs", f"{cell['config']}.json")
+    cfg = load_config(cell["config"])
     mix = gen.load_mix(cell["traffic"])
     return spec, cell, cfg, mix
+
+
+def load_config(name: str) -> dict:
+    """``bench/configs/<name>.json``; an error where it names no model
+    family, or one without its files (``bench/family.py``)."""
+    cfg = load_json(BENCH, "configs", f"{name}.json")
+    family.check(cfg)
+    return cfg
 
 
 def configure_jax(cfg: dict) -> None:
@@ -141,6 +152,16 @@ class Measurement:
 
     def in_window(self, t: float) -> bool:
         return self.t0 <= t <= self.t1
+
+
+def traced_stretch(online, seconds: float) -> tuple:
+    """(start, end) of the traced stretch, in seconds after the window
+    opens: the window's last ``TRACE_SECONDS``, or as many from the last
+    online arrival where that comes earlier -- a window whose end is quiet
+    would leave the trace with no device work."""
+    span = min(TRACE_SECONDS, seconds)
+    start = min(seconds - span, max((r.due for r in online), default=0.0))
+    return start, start + span
 
 
 class CellRun:
@@ -212,9 +233,9 @@ class CellRun:
     # ------------------------------------------------------------ window
     def _trace_hook(self) -> None:
         """Engine thread, between iterations (the serial engine has read
-        its tokens back, so the device is drained): start the profiler for
-        the window's last seconds, stop it at the first iteration boundary
-        after the window closes."""
+        its tokens back, so the device is drained): start the profiler at
+        ``trace_at``, stop it at the first iteration boundary after
+        ``trace_end``."""
         import jax
 
         now = time.perf_counter()
@@ -222,7 +243,7 @@ class CellRun:
             jax.profiler.start_trace(TRACE_DIR)
             self.trace_t[0] = now
             self.trace_state = "on"
-        elif self.trace_state == "on" and now >= self.t1:
+        elif self.trace_state == "on" and now >= self.trace_end:
             self.trace_t[1] = now
             jax.profiler.stop_trace()
             self.trace_state = "done"
@@ -270,7 +291,8 @@ class CellRun:
         self.t1 = self.t0 + self.seconds
         if self.traced:
             shutil.rmtree(TRACE_DIR, ignore_errors=True)
-            self.trace_at = self.t1 - min(TRACE_SECONDS, self.seconds)
+            a, b = traced_stretch(self.traffic.online, self.seconds)
+            self.trace_at, self.trace_end = self.t0 + a, self.t0 + b
             self.trace_state = "pending"
         self.offset = t - self.rt.now()  # runtime clock -> perf_counter
         gen_thread = threading.Thread(target=self._generate, name="bench-load")

@@ -40,7 +40,7 @@ def main(argv=None) -> None:
     ap.add_argument("--rates", required=True)
     ap.add_argument("--warm-seqs", type=int, default=None)
     args = ap.parse_args(argv)
-    cfg = bench_run.load_json(bench_run.BENCH, "configs", f"{args.config}.json")
+    cfg = bench_run.load_config(args.config)
     mix = gen.load_mix(args.traffic)
     bench_run.configure_jax(cfg)
     bench_run.devices_or_exit(1)

@@ -8,6 +8,7 @@ import shutil
 import numpy as np
 import pytest
 
+from bench import family
 from bench import run as bench_run
 from bench import traffic as gen
 from bench.tests import tiny
@@ -76,6 +77,24 @@ def test_gamma_gaps_keep_rate_and_burstiness():
     g = gen.gamma_gaps(4.0, 2.0, 4000)
     assert g.sum() == pytest.approx(1000.0)
     assert g.std() / g.mean() == pytest.approx(2.0, rel=0.1)
+
+
+@pytest.mark.parametrize("seed", [3000001643, 3000001631, 2**40 + 9])
+def test_traced_stretch_holds_the_last_arrival(seed):
+    """The traced stretch is the window's last seconds, or starts at the
+    last online arrival where that is earlier (seed 3000001643's last
+    arrival is due 11 s before the window closes: its last 5 s held no
+    device work on the chip)."""
+    cfg = bench_run.load_config("qwen2-0.5b")
+    mix = gen.load_mix("online-chat")
+    online = gen.generate(mix, cfg, cfg["vocab_size"], seed, 51.0).online
+    last = max(r.due for r in online)
+    a, b = bench_run.traced_stretch(online, 51.0)
+    assert b - a == bench_run.TRACE_SECONDS and b <= 51.0
+    assert a <= last < b or a == 51.0 - bench_run.TRACE_SECONDS
+    if last < 51.0 - bench_run.TRACE_SECONDS:
+        assert a == last
+    assert bench_run.traced_stretch(online, 3.0) == (0.0, 3.0)
 
 
 # -------------------------------------------------------------------- tails
@@ -153,11 +172,16 @@ def test_every_cell_reports_what_its_metrics_move():
 
 
 def test_configs_match_their_files():
+    """Each configuration's file matches its entry and names a model family
+    whose three modules exist (``bench/family.py``)."""
     for c in SPEC["configs"]:
         f = bench_run.load_json(ROOT, c["file"])
         assert f["name"] == c["name"] and f["source"] == c["source"]
         assert f["reduced"] == c["reduced"]
         assert f["precision"]["matmul"] == "bfloat16"
+        name = family.check(f)
+        for part in family.PARTS:
+            assert os.path.isfile(os.path.join(family.FAMILIES, name, f"{part}.py"))
 
 
 # ------------------------------------------------- found by name from files
@@ -179,6 +203,7 @@ def test_new_config_mix_and_metric_are_found_by_name(tmp_path, monkeypatch):
     monkeypatch.setattr(bench_run, "ROOT", str(tmp_path))
     monkeypatch.setattr(bench_run, "BENCH", str(bench))
     monkeypatch.setattr(gen, "HERE", str(bench))
+    monkeypatch.setattr(family, "FAMILIES", str(bench / "families"))
     got_spec, cell, got_cfg, mix = bench_run.load_cell("tiny-new.short-new")
     assert got_cfg["name"] == "tiny-new" and "offline" not in mix
     assert bench_run.read_reader("new_metric.online")(None) == 42.0
